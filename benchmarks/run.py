@@ -20,6 +20,7 @@ import time
 import traceback
 
 from benchmarks.common import print_rows, save_rows
+from repro.common.compile_cache import enable_compile_cache
 from repro.core.scheduler import available_policies
 
 #: module -> paper reference
@@ -54,6 +55,7 @@ def main() -> None:
                     help="scheduling policies to sweep in the benchmarks "
                          "that support it (rows carry the policy name)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = args.only or list(TABLES)
     failures = []

@@ -43,6 +43,10 @@ from __future__ import annotations
 
 import math
 
+#: bound on each float term of the adaptive K target before int(), far
+#: past any k_max
+_TERM_CAP = 2.0**31
+
 # ---------------------------------------------------------------------------
 # Registry (same shape as core/scheduler.py's policy registry)
 # ---------------------------------------------------------------------------
@@ -298,8 +302,11 @@ class AdaptiveSpecController(SpeculationController):
         p = min(max(self.alpha, 0.05), 0.95)
         # largest k with p^k >= gain_floor  <=>  k <= ln(floor)/ln(p)
         k_p = int(math.log(self.gain_floor) / math.log(p))
-        k_rtt = int(round(self.rtt * self.draft_speed * self.rtt_gain))
-        k_load = int(self.load / self.load_scale)
+        # each term saturates at _TERM_CAP before int(): a huge but finite
+        # rtt or queue depth can overflow the float product to inf
+        k_rtt = int(round(min(self.rtt * self.draft_speed * self.rtt_gain,
+                              _TERM_CAP)))
+        k_load = int(min(self.load / self.load_scale, _TERM_CAP))
         return max(1, min(k_p + k_rtt - k_load, self.k_max))
 
     def _step_towards(self, target: int) -> int:

@@ -10,6 +10,8 @@ deterministic failure/straggler injection (`ClusterConfig.fail_at` /
 """
 from __future__ import annotations
 
+import jax
+
 from repro.fleet.router import FleetCapacityError, FleetRouter
 from repro.fleet.runtime import FleetRuntime
 
@@ -38,6 +40,7 @@ def build_verifier_fleet(
     spill_quantize: bool = False,
     spill_idle_epochs: int = 2,
     tenants=None,
+    devices=None,
 ) -> FleetRouter:
     """N same-seed verifiers (each its own engine + page pool + scheduler
     instance) behind a prefix-locality router.  ``max_slots`` is PER
@@ -48,7 +51,13 @@ def build_verifier_fleet(
     ``tenants`` (a `TenantRegistry`, or an iterable of `TenantSpec` /
     CLI spec strings) is instantiated ONCE and shared by every verifier:
     tenant budgets and fair-share accounting are fleet-global, which is
-    what a fleet-wide SLO means (DESIGN.md §13)."""
+    what a fleet-wide SLO means (DESIGN.md §13).
+
+    Replica i lives on ``devices[i % len(devices)]`` (default
+    ``jax.devices()``): its copy of the params, its page pools, staging
+    buffers and rng keys, so on a multi-chip host each verifier holds its
+    own chip.  Replicas on the device that already holds ``tparams`` share
+    them (``device_put`` there makes no copy)."""
     from repro.serving.engine import VerificationEngine
     from repro.serving.server import WISPServer
     from repro.tenancy import TenantRegistry
@@ -57,13 +66,16 @@ def build_verifier_fleet(
         tenants = TenantRegistry(tenants)
     registry = tenants if tenants is not None else TenantRegistry()
 
+    devices = list(devices or jax.devices())
     verifiers = {}
     for i in range(int(n_verifiers)):
+        device = devices[i % len(devices)]
         engine = VerificationEngine(
-            model_cfg, tparams, max_slots=max_slots, max_len=max_len,
+            model_cfg, jax.device_put(tparams, device),
+            max_slots=max_slots, max_len=max_len,
             method=method, seed=engine_seed,
             kv_tier_pages=kv_tier_pages, spill_quantize=spill_quantize,
-            spill_idle_epochs=spill_idle_epochs,
+            spill_idle_epochs=spill_idle_epochs, device=device,
         )
         verifiers[f"v{i}"] = WISPServer(
             engine, coeffs, policy=policy, sched_cfg=sched_cfg,

@@ -37,6 +37,8 @@ import dataclasses
 from repro.core.estimator import BatchShape
 from repro.runtime.failure import HeartbeatMonitor
 from repro.runtime.straggler import HedgedDispatcher
+from repro.serving.engine import NoFreeSlots
+from repro.serving.kv_cache import OutOfPages
 from repro.serving.events import Migrated, VerifierDown
 from repro.tenancy import DEFAULT_TENANT
 
@@ -346,7 +348,7 @@ class FleetRouter:
                     alpha=m.alpha, spec_k=m.spec_k,
                     extras=m.extras, now=now, tenant=m.tenant,
                 )
-            except Exception as e:          # OutOfPages / NoFreeSlots
+            except (OutOfPages, NoFreeSlots) as e:
                 last_err = e
                 continue
             self.owner[session_id] = dst

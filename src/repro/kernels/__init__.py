@@ -7,7 +7,8 @@
   logit_features   — fused single-pass rejection-predictor features
 
 Each kernel ships <name>.py (pl.pallas_call + BlockSpec), ops.py (public
-jit'd wrapper with backend dispatch) and ref.py (pure-jnp oracle).
+jit'd wrapper) and ref.py (pure-jnp oracle); ``backend.interpret()`` picks
+compiled (TPU) or interpreted (CPU) execution for all three.
 """
 from repro.kernels.verify_attention.ops import verify_attention_op, verify_attention_ref
 from repro.kernels.paged_attention.ops import paged_attention_op, paged_attention_ref

@@ -1,5 +1,5 @@
 """Public entry for paged decode attention: Pallas on TPU, interpret mode
-elsewhere.
+on the CPU (`repro.kernels.backend`).
 
 Two consumers:
   * plain decode — ``paged_attention_op``, one query token per sequence;
@@ -16,9 +16,9 @@ the reserved scratch page 0 so they can never clobber live pages.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.paged_attention.paged_attention import paged_attention as _kernel
 from repro.kernels.paged_attention.ref import (
     gather_pages,
@@ -28,7 +28,7 @@ from repro.kernels.paged_attention.ref import (
 
 
 def paged_attention_op(q, k_pages, v_pages, block_table, lengths, *, softcap=0.0):
-    interpret = jax.default_backend() != "tpu"
+    interpret = backend.interpret()
     return _kernel(
         q, k_pages, v_pages, block_table, lengths,
         softcap=softcap, interpret=interpret,
@@ -37,8 +37,8 @@ def paged_attention_op(q, k_pages, v_pages, block_table, lengths, *, softcap=0.0
 
 def paged_verify_attention_op(
     q,                 # (B, T, H, D) new tokens at positions base..base+T-1
-    k_pages,           # (n_pages, P, Hkv, D) — new K/V already scattered in
-    v_pages,           # (n_pages, P, Hkv, D)
+    k_pages,           # (n_pages, Hkv, P, D) — new K/V already scattered in
+    v_pages,           # (n_pages, Hkv, P, D)
     block_table,       # (B, n_max) int32
     base_lens,         # (B,) int32 committed kv tokens BEFORE the new block
     *,
@@ -61,7 +61,7 @@ def paged_verify_attention_op(
 
 
 def scatter_kv_pages(
-    k_pages,           # (n_pages, P, Hkv, D) one layer's pages
+    k_pages,           # (n_pages, Hkv, P, D) one layer's pages
     v_pages,
     k_new,             # (B, T, Hkv, D) K/V of the new tokens
     v_new,
@@ -76,7 +76,7 @@ def scatter_kv_pages(
     batch rows) are redirected to scratch page 0: distinct live rows write
     disjoint pages, so the only scatter collisions are garbage-on-garbage
     inside the scratch page."""
-    n_pages, P = k_pages.shape[:2]
+    P = k_pages.shape[2]
     B, T = k_new.shape[:2]
     n_max = block_table.shape[1]
     pos = base_lens[:, None] + jnp.arange(T)[None, :]               # (B, T)
@@ -85,8 +85,10 @@ def scatter_kv_pages(
     pid = jnp.take_along_axis(block_table, slot, axis=1)            # (B, T)
     pid = jnp.where(valid, pid, 0)
     off = jnp.where(valid, pos % P, 0)
-    k_pages = k_pages.at[pid, off].set(k_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[pid, off].set(v_new.astype(v_pages.dtype))
+    # (pid, :, off) indexes a (B, T, Hkv, D) view: the advanced indices
+    # split by the head slice broadcast to the front, matching k_new
+    k_pages = k_pages.at[pid, :, off].set(k_new.astype(k_pages.dtype))
+    v_pages = v_pages.at[pid, :, off].set(v_new.astype(v_pages.dtype))
     return k_pages, v_pages
 
 

@@ -9,17 +9,17 @@ import jax.numpy as jnp
 
 
 def gather_pages(pages, block_table):
-    """pages: (n_pages, P, Hkv, D); block_table: (B, n_max) ->
-    (B, n_max*P, Hkv, D)."""
-    g = pages[block_table]                       # (B, n_max, P, Hkv, D)
-    B, n_max, P = g.shape[:3]
-    return g.reshape(B, n_max * P, *g.shape[3:])
+    """pages: (n_pages, Hkv, P, D); block_table: (B, n_max) ->
+    dense token-major (B, n_max*P, Hkv, D)."""
+    g = pages[block_table]                       # (B, n_max, Hkv, P, D)
+    B, n_max, Hkv, P, D = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, n_max * P, Hkv, D)
 
 
 def paged_verify_attention_ref(
     q,                 # (B, T, H, D) new tokens at positions base..base+T-1
-    k_pages,           # (n_pages, P, Hkv, D) — new K/V already scattered in
-    v_pages,           # (n_pages, P, Hkv, D)
+    k_pages,           # (n_pages, Hkv, P, D) — new K/V already scattered in
+    v_pages,           # (n_pages, Hkv, P, D)
     block_table,       # (B, n_max) int32
     base_lens,         # (B,) int32 committed kv tokens BEFORE the new block
     *,
@@ -30,7 +30,7 @@ def paged_verify_attention_ref(
     attends to kv positions < base_lens[b] + t + 1 (history + the new
     tokens up to and including itself)."""
     B, T, H, D = q.shape
-    Hkv = k_pages.shape[2]
+    Hkv = k_pages.shape[1]
     G = H // Hkv
     scale = scale if scale is not None else D**-0.5
     k = gather_pages(k_pages, block_table).astype(jnp.float32)
@@ -51,8 +51,8 @@ def paged_verify_attention_ref(
 
 def paged_attention_ref(
     q,                 # (B, H, D) one new token per sequence
-    k_pages,           # (n_pages, P, Hkv, D)
-    v_pages,           # (n_pages, P, Hkv, D)
+    k_pages,           # (n_pages, Hkv, P, D)
+    v_pages,           # (n_pages, Hkv, P, D)
     block_table,       # (B, n_max) int32
     lengths,           # (B,) int32 valid kv tokens (including current)
     *,
@@ -60,7 +60,7 @@ def paged_attention_ref(
     scale=None,
 ):
     B, H, D = q.shape
-    Hkv = k_pages.shape[2]
+    Hkv = k_pages.shape[1]
     G = H // Hkv
     scale = scale if scale is not None else D**-0.5
     k = gather_pages(k_pages, block_table).astype(jnp.float32)

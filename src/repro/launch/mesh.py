@@ -15,9 +15,7 @@ import jax
 
 
 def _mesh_kwargs(n):
-    # AxisType landed after jax 0.4; older runtimes default to Auto anyway
-    at = getattr(jax.sharding, "AxisType", None)
-    return {} if at is None else {"axis_types": (at.Auto,) * n}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -21,6 +21,9 @@ Example:
       --reduced --devices 4 --rounds 8 --policy wisp
   python -m repro.launch.serve --devices 4 --rounds 8 --policy edf
   python -m repro.launch.serve --devices 4 --rounds 8 --sync   # lock-step
+  python -m repro.launch.serve --target qwen2-7b-14l --no-reduced \
+      --self-draft --devices 4 --rounds 3 --prefill chunked \
+      --q-mode compact                                   # one TPU chip
 """
 from __future__ import annotations
 
@@ -32,6 +35,7 @@ import jax
 
 from repro.cluster import ClusterConfig, ClusterRuntime, build_fleet
 from repro.cluster.workload import TENANT_MIXES, build_tenant_registry
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core.estimator import EstimatorCoeffs, analytic_tpu_coeffs
 from repro.core.scheduler import available_policies
@@ -98,6 +102,7 @@ def run_serving(
     spill_idle_epochs: int = 2,
     tenants=None,
     tenant_mix=None,
+    verifier_devices=None,
 ):
     """Run the WISP serving stack; returns a dict with per-device ``stats``,
     aggregate ``total``, the ``edges`` / ``server`` objects and — in
@@ -127,7 +132,12 @@ def run_serving(
     into per-tenant groups and compiles their admission contracts into
     a shared `TenantRegistry`; ``tenants`` adds/overrides registry
     entries (`TenantSpec` objects or ``name[:key=value]*`` CLI spec
-    strings).  Both empty = the legacy single-tenant stack."""
+    strings).  Both empty = the legacy single-tenant stack.
+
+    ``verifier_devices`` (with ``verifiers > 1``) overrides where the
+    fleet's replicas live (`build_verifier_fleet`; default: replica i on
+    ``jax.devices()[i % n]``) — ``[d] * verifiers`` stacks them all on
+    ``d``."""
     if scheduler is not None:
         if policy != "wisp" and policy != scheduler:
             raise ValueError(
@@ -154,8 +164,7 @@ def run_serving(
     if dcfg.vocab != tcfg.vocab:
         raise ValueError("draft/target vocab mismatch")
 
-    tb = build(tcfg)
-    tparams = tb.init(jax.random.PRNGKey(seed))
+    tparams = jax.jit(build(tcfg).init)(jax.random.PRNGKey(seed))
     if self_draft:
         # self-speculation: the draft IS the target (with greedy drafting
         # and greedy verification every block fully accepts and every
@@ -163,7 +172,7 @@ def run_serving(
         # bound)
         dcfg, dparams = tcfg, tparams
     else:
-        dparams = build(dcfg).init(jax.random.PRNGKey(seed + 1))
+        dparams = jax.jit(build(dcfg).init)(jax.random.PRNGKey(seed + 1))
 
     if sync and prefill_mode != "zero":
         # the lock-step reference has no clock to charge prefill against;
@@ -256,7 +265,7 @@ def run_serving(
             hedge_factor=hedge_factor, hedge_guard=hedge_guard,
             kv_tier_pages=kv_tier_pages, spill_quantize=spill_quantize,
             spill_idle_epochs=spill_idle_epochs,
-            tenants=registry,
+            tenants=registry, devices=verifier_devices,
         )
         engine = next(iter(router.verifiers.values())).engine
         server = router
@@ -483,7 +492,14 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--target", default="qwen2-7b")
     ap.add_argument("--draft", default=None)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--self-draft", action="store_true",
+                    help="edges draft with the target's own params: no "
+                         "second model (what fits one chip at published "
+                         "widths)")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="tiny same-family widths (the default); "
+                         "--no-reduced serves the config's published widths")
     ap.add_argument("--devices", type=int, default=4)
     ap.add_argument("--rounds", type=int, default=8)
     ap.add_argument("--k-max", type=int, default=6)
@@ -598,10 +614,13 @@ def main():
             "flags compile onto the schedule for now",
             DeprecationWarning, stacklevel=2,
         )
+    enable_compile_cache()
     pred = RejectionPredictor.load(args.predictor_path) if args.predictor_path else None
     run_serving(
-        args.target, args.draft, devices=args.devices, rounds=args.rounds,
+        args.target, args.draft, reduced=args.reduced,
+        devices=args.devices, rounds=args.rounds,
         k_max=args.k_max, policy=args.policy, predictor=pred,
+        self_draft=args.self_draft,
         spec_policy=args.spec_policy,
         seed=args.seed, sync=args.sync, speculate=not args.no_speculate,
         churn=args.churn, horizon=args.horizon if args.churn else None,

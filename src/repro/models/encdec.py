@@ -245,7 +245,7 @@ def encdec_decode_paged(
     cfg,
     params,
     tokens,            # (B, T) new tokens at positions base_lens[b] + t
-    k_pages,           # (n_layers, n_pages, P, Hkv, hd) decoder self-attn KV
+    k_pages,           # (n_layers, n_pages, Hkv, P, hd) decoder self-attn KV
     v_pages,
     block_table,       # (B, n_max) int32
     base_lens,         # (B,) int32

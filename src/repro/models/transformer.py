@@ -456,7 +456,7 @@ def decode_paged(
     cfg: ArchConfig,
     params,
     tokens,            # (B, T) new tokens at positions base_lens[b] + t
-    k_pages,           # (n_layers, n_pages, P, Hkv, hd)
+    k_pages,           # (n_layers, n_pages, Hkv, P, hd)
     v_pages,
     block_table,       # (B, n_max) int32 page ids per row
     base_lens,         # (B,) int32 committed kv tokens per row

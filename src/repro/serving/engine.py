@@ -110,6 +110,55 @@ def _bucket(n: int, lo: int = 1) -> int:
     return b
 
 
+def fused_paged_verify(decode_raw):
+    """The paged backend's fused verify program: ``decode_raw`` (a paged
+    decode with the config bound) over the verify block, then the accept
+    rule — one jit dispatch returning ``(accept_len, token, (k_pages,
+    v_pages))``.  The page pools (args 2/3) are donated so XLA updates
+    them in place instead of copying the whole pool every epoch."""
+    def fused(params, feed, kp, vp, bt, base, tl, cross, draft, dlen,
+              rng, tags, qargs, *, method, tagged):
+        logits, (kp, vp) = decode_raw(params, feed, kp, vp, bt, base,
+                                      tl, cross)
+        out = verify_epoch_rule(
+            rng, draft, dlen, logits, method=method,
+            rng_tags=tags if tagged else None, **qargs,
+        )
+        return out["accept_len"], out["token"], (kp, vp)
+
+    return jax.jit(fused, static_argnames=("method", "tagged"),
+                   donate_argnums=(2, 3))
+
+
+def fused_paged_verify_specs(cfg, *, batch: int, k: int, n_pages: int,
+                             n_max: int, top_c: int,
+                             page_size: int = PAGE_SIZE,
+                             cache_dtype=jnp.float32, sharding=None):
+    """Shape stand-ins for every positional argument of the dense/moe
+    ``fused_paged_verify`` program in compact-q residual mode, as the
+    engine stages them: lower and compile it for a described chip without
+    allocating the model (``sharding`` places each stand-in)."""
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=sharding)
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(build(cfg).init, jax.random.PRNGKey(0)),
+    )
+    pool = S((cfg.n_layers, n_pages, cfg.n_kv_heads, page_size,
+              cfg.resolved_head_dim), cache_dtype)
+    i32, f32 = jnp.int32, jnp.float32
+    qargs = {
+        "logq_tok": S((batch, k), f32),
+        "top_idx": S((batch, k, top_c), i32),
+        "top_logq": S((batch, k, top_c), f32),
+        "tail": S((batch, k), f32),
+    }
+    return (params, S((batch, k + 1), i32), pool, pool,
+            S((batch, n_max), i32), S((batch,), i32), S((batch,), i32),
+            None, S((batch, k), i32), S((batch,), i32),
+            S((2,), jnp.uint32), S((batch, 2), i32), qargs)
+
+
 def _log_softmax1(x):
     """log_softmax at temperature 1.0, matching `speculative._log_softmax`
     bit-for-bit (x / max(1.0, eps) == x exactly)."""
@@ -215,8 +264,12 @@ class VerificationEngine:
         kv_tier_pages: int = 0,
         spill_quantize: bool = False,
         spill_idle_epochs: int = 2,
+        device=None,
     ):
         self.cfg = cfg
+        #: the device holding this engine's pools, staging and rng keys
+        #: (None: JAX's default device); params must already live there
+        self.device = device
         self.bundle = build(cfg)
         self.params = params
         self.max_slots = max_slots
@@ -232,9 +285,9 @@ class VerificationEngine:
         self.fed = np.zeros(max_slots, np.int64)        # KV-valid tokens/slot
         self.last_token = np.zeros(max_slots, np.int64) # committed[-1]/slot
         self.free_slots = list(range(max_slots - 1, -1, -1))
-        self.rng = jax.random.PRNGKey(seed)
+        self.rng = self._put(jax.random.PRNGKey(seed))
         #: never advanced: base for rng_tag-keyed (deterministic) verification
-        self._rng_base = jax.random.PRNGKey(seed)
+        self._rng_base = self._put(jax.random.PRNGKey(seed))
         #: pooled, bucket-keyed host staging buffers (DESIGN.md §9): one
         #: allocation per (shape bucket, q representation), reused across
         #: calls.  Rows written by the previous call are reset to their pad
@@ -295,6 +348,8 @@ class VerificationEngine:
         else:
             self.cache = self.bundle.init_cache(max_slots, max_len, dtype=cache_dtype) \
                 if cfg.family != "ssm" else self.bundle.init_cache(max_slots, max_len)
+            if self.device is not None:
+                self.cache = jax.device_put(self.cache, self.device)
             self._bax = _batch_axis_tree(self.bundle.cache_axes())
             self._decode = jax.jit(self.bundle.decode)
             self._prefill = jax.jit(self.bundle.prefill)
@@ -316,7 +371,7 @@ class VerificationEngine:
         self.kv = PagedKV(
             cfg.n_layers, n_pages, hkv, hd,
             page_size=page_size, dtype=cache_dtype,
-            tier=self._tier_cfg, counters=self.stats,
+            tier=self._tier_cfg, counters=self.stats, device=self.device,
         )
         #: prefix sharing is sound only when KV is a pure function of the
         #: token ids — cross-attention families condition on extras.
@@ -331,7 +386,7 @@ class VerificationEngine:
             n_groups = cfg.n_layers // cfg.cross_attn_every
             z = lambda: jnp.zeros(
                 (n_groups, self.max_slots, cfg.num_image_tokens, hkv, hd),
-                cache_dtype,
+                cache_dtype, device=self.device,
             )
             self.extras_cache = {"k_img": z(), "v_img": z()}
             self._extras_key = "image_embeds"
@@ -343,7 +398,7 @@ class VerificationEngine:
         elif cfg.family == "audio":
             z = lambda: jnp.zeros(
                 (cfg.n_layers, self.max_slots, cfg.encoder_frames, hkv, hd),
-                cache_dtype,
+                cache_dtype, device=self.device,
             )
             self.extras_cache = {"k_mem": z(), "v_mem": z()}
             self._extras_key = "frames"
@@ -357,7 +412,7 @@ class VerificationEngine:
             self._prefill_paged = _jit(
                 partial(transformer.decode_paged, cfg, dropless=False)
             )
-        self._fused_verify = self._build_fused_paged(decode_raw)
+        self._fused_verify = fused_paged_verify(decode_raw)
 
     # -- fused per-epoch verify programs (DESIGN.md §9) -----------------------
     # Each program is ONE jit dispatch: target forward + the accept/reject
@@ -524,23 +579,13 @@ class VerificationEngine:
         return jax.jit(fused, static_argnames=("method", "tagged"),
                        donate_argnums=(1,))
 
-    def _build_fused_paged(self, decode_raw):
-        def fused(params, feed, kp, vp, bt, base, tl, cross, draft, dlen,
-                  rng, tags, qargs, *, method, tagged):
-            logits, (kp, vp) = decode_raw(params, feed, kp, vp, bt, base,
-                                          tl, cross)
-            out = verify_epoch_rule(
-                rng, draft, dlen, logits, method=method,
-                rng_tags=tags if tagged else None, **qargs,
-            )
-            return out["accept_len"], out["token"], (kp, vp)
-
-        return jax.jit(fused, static_argnames=("method", "tagged"),
-                       donate_argnums=(2, 3))
+    def _put(self, x, dtype=None):
+        """Host data onto this engine's device (JAX's default if unplaced)."""
+        return jnp.asarray(x, dtype, device=self.device)
 
     # -- slot/cache plumbing (dense backend) ----------------------------------
     def _gather(self, slots):
-        idx = jnp.asarray(slots, jnp.int32)
+        idx = self._put(slots, jnp.int32)
         return jax.tree.map(
             lambda leaf, ax: jnp.take(leaf, idx, axis=ax, mode="clip"),
             self.cache, self._bax,
@@ -559,7 +604,7 @@ class VerificationEngine:
 
     # -- extras side cache (paged vlm/audio: batch axis is 1) -----------------
     def _extras_gather(self, slots):
-        idx = jnp.asarray(slots, jnp.int32)
+        idx = self._put(slots, jnp.int32)
         return jax.tree.map(
             lambda leaf: jnp.take(leaf, idx, axis=1, mode="clip"),
             self.extras_cache,
@@ -738,7 +783,7 @@ class VerificationEngine:
         if self.extras_cache is not None:
             self._count_dispatch("extras")
             k_x, v_x = self._extras_builder(
-                self.params, jnp.asarray(extras[self._extras_key])
+                self.params, self._put(extras[self._extras_key])
             )
             keys = sorted(self.extras_cache)          # (k_img, v_img) / (k_mem, v_mem)
             self._extras_put(slot, {keys[0]: k_x, keys[1]: v_x})
@@ -842,19 +887,23 @@ class VerificationEngine:
             [st.slot for st, _ in live], n_max
         )
         bufs["_hw"] = len(live)
+        # the program gets private copies: this call waits on the device
+        # only when a prompt finishes, the next one rewrites the pooled
+        # buffers, and a CPU device array may alias its numpy source
+        staged = {k: bufs[k].copy() for k in ("feed", "bt", "base", "tl", "slots")}
         cross = (
-            self._extras_gather(bufs["slots"])
+            self._extras_gather(staged["slots"])
             if self.extras_cache is not None else None
         )
         self._count_dispatch("prefill")
         logits, (kp, vp) = self._prefill_paged(
             self.params,
-            jnp.asarray(bufs["feed"]),
+            self._put(staged["feed"]),
             self.kv.k_pages,
             self.kv.v_pages,
-            jnp.asarray(bufs["bt"]),
-            jnp.asarray(bufs["base"]),
-            jnp.asarray(bufs["tl"]),
+            self._put(staged["bt"]),
+            self._put(staged["base"]),
+            self._put(staged["tl"]),
             cross,
         )
         self.kv.k_pages, self.kv.v_pages = kp, vp
@@ -870,8 +919,8 @@ class VerificationEngine:
             # one device-side argmax + ONE transfer for every chunk that
             # completed its prompt this call (was: a blocking
             # int(jnp.argmax(...)) sync per finished row)
-            ridx = jnp.asarray([i for i, _, _ in finished], jnp.int32)
-            cpos = jnp.asarray([n - 1 for _, n, _ in finished], jnp.int32)
+            ridx = self._put([i for i, _, _ in finished], jnp.int32)
+            cpos = self._put([n - 1 for _, n, _ in finished], jnp.int32)
             firsts = np.asarray(
                 jax.device_get(jnp.argmax(logits[ridx, cpos], axis=-1))
             )
@@ -899,7 +948,7 @@ class VerificationEngine:
         padded[0, :n] = chunk
         sub = self._gather([st.slot])
         if s0 == 0:
-            batch = {"tokens": jnp.asarray(padded)}
+            batch = {"tokens": self._put(padded)}
             if st.extras:
                 batch.update(st.extras)
             self._count_dispatch("prefill")
@@ -907,7 +956,7 @@ class VerificationEngine:
         else:
             self._count_dispatch("prefill")
             logits, sub = self._decode(
-                self.params, jnp.asarray(padded), sub, jnp.int32(s0)
+                self.params, self._put(padded), sub, jnp.int32(s0)
             )
         self._scatter([st.slot], sub, 1)
         st.done += n
@@ -1064,11 +1113,11 @@ class VerificationEngine:
         qargs = {}
         q_bytes = 0
         if q_kind == "dense":
-            qargs["q_logits"] = jnp.asarray(bufs["qlog"])
+            qargs["q_logits"] = self._put(bufs["qlog"])
             q_bytes = bufs["qlog"].nbytes
         elif q_kind == "compact":
             for name in ("logq_tok", "top_idx", "top_logq", "tail"):
-                qargs[name] = jnp.asarray(bufs[name])
+                qargs[name] = self._put(bufs[name])
                 q_bytes += bufs[name].nbytes
         core_bytes = (bufs["draft"].nbytes + bufs["dlen"].nbytes
                       + bufs["feed"].nbytes + bufs["pos"].nbytes
@@ -1076,10 +1125,10 @@ class VerificationEngine:
         self.stats["h2d_bytes"] += core_bytes + q_bytes
         self.stats["h2d_q_bytes"] += q_bytes
 
-        draft_d = jnp.asarray(bufs["draft"])
-        dlen_d = jnp.asarray(bufs["dlen"])
-        feed_d = jnp.asarray(bufs["feed"])
-        tags_d = jnp.asarray(bufs["tags"])
+        draft_d = self._put(bufs["draft"])
+        dlen_d = self._put(bufs["dlen"])
+        feed_d = self._put(bufs["feed"])
+        tags_d = self._put(bufs["tags"])
 
         if self.paged:
             acc_d, tok_d = self._dispatch_verify_paged(
@@ -1089,8 +1138,8 @@ class VerificationEngine:
         else:
             self._count_dispatch("verify")
             acc_d, tok_d, self.cache = self._fused_verify(
-                self.params, self.cache, jnp.asarray(bufs["slots"]),
-                feed_d, jnp.asarray(bufs["pos"]), draft_d, dlen_d,
+                self.params, self.cache, self._put(bufs["slots"]),
+                feed_d, self._put(bufs["pos"]), draft_d, dlen_d,
                 kv, tags_d, qargs, method=self.method, tagged=tagged,
             )
         # ONE device->host transfer carries the whole epoch's results
@@ -1151,8 +1200,8 @@ class VerificationEngine:
         self._count_dispatch("verify")
         acc_d, tok_d, (kp, vp) = self._fused_verify(
             self.params, feed_d, self.kv.k_pages, self.kv.v_pages,
-            jnp.asarray(btb["bt"]), jnp.asarray(bufs["pos"]),
-            jnp.asarray(bufs["tl"]), cross, draft_d, dlen_d,
+            self._put(btb["bt"]), self._put(bufs["pos"]),
+            self._put(bufs["tl"]), cross, draft_d, dlen_d,
             kv, tags_d, qargs, method=self.method, tagged=tagged,
         )
         self.kv.k_pages, self.kv.v_pages = kp, vp

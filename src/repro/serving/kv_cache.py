@@ -9,7 +9,11 @@ Host-side allocator state (free list, block tables, refcounts, prefix hash
 index) is plain Python — it runs on the serving coordinator.  Device arrays
 hold the actual pages:
 
-    k_pages, v_pages : (L, n_pages, page_size, Hkv, hd)
+    k_pages, v_pages : (L, n_pages, Hkv, page_size, hd)
+
+The page axis comes first (a page is one index on axis 1 across layers and
+heads); inside a page the KV head precedes the token axis, so the kernel's
+(page, head) tile is a full ``(page_size, hd)`` trailing block.
 
 The prefix cache is content-addressed at page granularity: a full page of
 committed tokens hashes (chained) to a page id; sessions sharing a prompt
@@ -91,7 +95,7 @@ class TierConfig:
 
 @dataclasses.dataclass
 class HostPage:
-    """One spilled page: ``(2, L, page_size, Hkv, hd)`` K/V stacked."""
+    """One spilled page: ``(2, L, Hkv, page_size, hd)`` K/V stacked."""
 
     fmt: str                      # "raw" | "int8"
     data: np.ndarray              # raw page bytes, or int8 codes
@@ -351,6 +355,7 @@ class PagedKV:
         dtype=jnp.bfloat16,
         tier: TierConfig | None = None,
         counters: dict | None = None,
+        device=None,
     ):
         self.page_size = page_size
         self.allocator = PageAllocator(n_pages, page_size)
@@ -360,9 +365,12 @@ class PagedKV:
         # reads of pad entries land on scratch and are masked by lengths.
         self.scratch_page = self.allocator.alloc()
         assert self.scratch_page == 0, "scratch must be page 0 (pad id)"
-        shape = (n_layers, n_pages, page_size, n_kv_heads, head_dim)
-        self.k_pages = jnp.zeros(shape, dtype)
-        self.v_pages = jnp.zeros(shape, dtype)
+        #: where the pools and every page written back into them live
+        #: (None: JAX's default device)
+        self.device = device
+        shape = (n_layers, n_pages, n_kv_heads, page_size, head_dim)
+        self.k_pages = jnp.zeros(shape, dtype, device=device)
+        self.v_pages = jnp.zeros(shape, dtype, device=device)
         self.tables: dict[int, SeqPages] = {}
         # -- host spill tier (DESIGN.md §12) -------------------------------
         self.tier = None
@@ -467,7 +475,7 @@ class PagedKV:
                 self.allocator.touch(ref)
 
     def _page_bytes(self, pid: int) -> np.ndarray:
-        """(2, L, page_size, Hkv, hd) stacked K/V of one device page."""
+        """(2, L, Hkv, page_size, hd) stacked K/V of one device page."""
         return np.asarray(jax.device_get(
             jnp.stack((self.k_pages[:, pid], self.v_pages[:, pid]))
         ))
@@ -555,9 +563,9 @@ class PagedKV:
         page = self.tier.get(handle)
         pid = self.allocator.alloc()
         self.k_pages = self.k_pages.at[:, pid].set(
-            jnp.asarray(page[0], dtype=self.k_pages.dtype))
+            jnp.asarray(page[0], dtype=self.k_pages.dtype, device=self.device))
         self.v_pages = self.v_pages.at[:, pid].set(
-            jnp.asarray(page[1], dtype=self.v_pages.dtype))
+            jnp.asarray(page[1], dtype=self.v_pages.dtype, device=self.device))
         return pid
 
     def _load_index_page(self, ref: int) -> int | None:
@@ -685,7 +693,8 @@ class PagedKV:
     def write_tokens(self, seq_id: int, start: int, k_new, v_new):
         """Write K/V for [start, start+T) of one sequence.
 
-        k_new/v_new: (L, T, Hkv, hd).  Functional-update of the page arrays
+        k_new/v_new: (L, T, Hkv, hd), token-major; the page stores each
+        run head-major, ``(L, Hkv, n, hd)``.  Functional-update of the page arrays
         (on TPU this is the fused scatter inside the verify kernel; the
         host path keeps semantics identical).
         """
@@ -701,11 +710,11 @@ class PagedKV:
             pid = t.pages[pos // ps]
             off = pos % ps
             n = min(ps - off, T - o)
-            self.k_pages = self.k_pages.at[:, pid, off : off + n].set(
-                k_new[:, o : o + n].astype(self.k_pages.dtype)
+            self.k_pages = self.k_pages.at[:, pid, :, off : off + n].set(
+                k_new[:, o : o + n].swapaxes(1, 2).astype(self.k_pages.dtype)
             )
-            self.v_pages = self.v_pages.at[:, pid, off : off + n].set(
-                v_new[:, o : o + n].astype(self.v_pages.dtype)
+            self.v_pages = self.v_pages.at[:, pid, :, off : off + n].set(
+                v_new[:, o : o + n].swapaxes(1, 2).astype(self.v_pages.dtype)
             )
             o += n
 
@@ -719,10 +728,9 @@ class PagedKV:
         n_pages_needed = (max_len + ps - 1) // ps
         pads = t.pages[:n_pages_needed] + [0] * (n_pages_needed - len(t.pages))
         idx = np.asarray(pads, np.int32)
-        k = self.k_pages[:, idx].reshape(
-            self.k_pages.shape[0], -1, *self.k_pages.shape[3:]
-        )[:, :max_len]
-        v = self.v_pages[:, idx].reshape(
-            self.v_pages.shape[0], -1, *self.v_pages.shape[3:]
-        )[:, :max_len]
-        return k, v
+
+        def dense(pages):
+            g = pages[:, idx].transpose(0, 1, 3, 2, 4)   # (L, n, P, Hkv, hd)
+            return g.reshape(g.shape[0], -1, *g.shape[3:])[:, :max_len]
+
+        return dense(self.k_pages), dense(self.v_pages)

@@ -246,6 +246,26 @@ def test_mutually_blocked_prefills_preempt_instead_of_livelock(dense_model):
     assert firsts[1] == want
 
 
+def test_paged_prefill_chunks_read_their_own_staging(dense_model):
+    """Back-to-back batched chunks commit the monolithic first tokens on
+    every run.  A chunk's call returns before the device runs it and the
+    next chunk rewrites the pooled host buffers; when a CPU device array
+    aliased its numpy source, the first tokens varied from run to run."""
+    cfg, _, params = dense_model
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(2, cfg.vocab, size=12).tolist() for _ in range(2)]
+    # one page holds each prompt, so every chunk reuses one pooled buffer set
+    kw = dict(paged=True, page_size=256, max_len=512)
+    mono = _engine(cfg, params, **kw)
+    want = [mono.new_session(p)[1] for p in prompts]
+    for _ in range(4):
+        eng = _engine(cfg, params, **kw)
+        states = [eng.begin_prefill(p) for p in prompts]
+        while not all(st.finished for st in states):
+            eng.step([PrefillChunkItem(st, 4) for st in states])
+        assert [st.first_token for st in states] == want
+
+
 def test_cluster_streams_invariant_to_prefill_mode(dense_model):
     """Fixed-work cluster runs under zero / monolithic / chunked prefill
     commit byte-identical streams (timing never reaches a sampling key);

@@ -10,10 +10,16 @@ import pytest
 from repro.cluster import ClusterConfig, ClusterRuntime, build_fleet
 from repro.configs import get_config
 from repro.core.estimator import EstimatorCoeffs
-from repro.fleet import FleetRouter, FleetRuntime, build_verifier_fleet
+from repro.fleet import (
+    FleetCapacityError,
+    FleetRouter,
+    FleetRuntime,
+    build_verifier_fleet,
+)
 from repro.models import build
 from repro.serving.client import EdgeDevice
 from repro.serving.engine import VerificationEngine
+from repro.serving.kv_cache import OutOfPages
 from repro.serving.server import WISPServer
 from repro.serving.transport import NetworkModel
 
@@ -234,7 +240,7 @@ def _golden_run(cfg, tparams, dparams):
 
 
 def _fleet_run(cfg, tparams, dparams, *, policy, schedule=None, verifiers=2,
-               **extra):
+               devices=None, **extra):
     """Chaos variants declare verifier faults through the unified seeded
     `FaultSchedule` DSL (``kill=IDX@T0[+DUR]`` / ``straggle=...``);
     legacy ``fail_at``/``straggle`` tuples ride through ``extra`` to pin
@@ -247,6 +253,7 @@ def _fleet_run(cfg, tparams, dparams, *, policy, schedule=None, verifiers=2,
         prefill="chunked", prefill_chunk_tokens=ccfg.prefill_chunk_tokens,
         heartbeat_timeout=ccfg.heartbeat_timeout,
         hedge_factor=ccfg.hedge_factor, hedge_guard=ccfg.hedge_guard,
+        devices=devices,
     )
     fleet = build_fleet(ccfg, cfg.vocab)
     edges = _edges(cfg, dparams, ccfg, fleet)
@@ -286,6 +293,69 @@ def test_chaos_fleet_without_failures_matches_golden(dense_pair,
     streams, router, _ = _fleet_run(cfg, tparams, dparams, policy="wisp")
     assert router.stats["verifier_downs"] == 0
     assert streams == golden_streams
+
+
+def test_fleet_on_explicit_devices_matches_golden(dense_pair, golden_streams):
+    """Replicas placed on explicit devices (each with its own params copy,
+    page pools, staging and rng keys there) commit the streams of the
+    shared-default-device fleet."""
+    cfg, tparams, dparams = dense_pair
+    dev = jax.devices()[0]
+    streams, router, _ = _fleet_run(cfg, tparams, dparams, policy="wisp",
+                                    devices=[dev, dev])
+    assert streams == golden_streams
+    for srv in router.verifiers.values():
+        eng = srv.engine
+        assert eng.device == dev
+        assert eng.kv.k_pages.devices() == {dev}
+        assert eng.rng.devices() == {dev}
+
+
+@pytest.mark.parametrize("devices", [None, "first"])
+def test_fleet_replicas_cycle_over_devices(dense_pair, devices):
+    """Replica i lands on ``devices[i % len(devices)]`` (default: every
+    device JAX sees); replicas on the params' own device share their
+    buffers instead of copying them."""
+    cfg, tparams, _ = dense_pair
+    given = jax.devices()[:1] if devices == "first" else None
+    router = build_verifier_fleet(cfg, tparams, 3, COEFFS, max_slots=2,
+                                  max_len=64, devices=given)
+    pool = given or jax.devices()
+    src = jax.tree.leaves(tparams)
+    for i, srv in enumerate(router.verifiers.values()):
+        eng = srv.engine
+        assert eng.device == pool[i % len(pool)]
+        assert eng.kv.k_pages.devices() == {eng.device}
+        if eng.device in src[0].devices():
+            assert [a.unsafe_buffer_pointer()
+                    for a in jax.tree.leaves(eng.params)] == [
+                a.unsafe_buffer_pointer() for a in src]
+
+
+@pytest.mark.parametrize("fault,raised,tried", [
+    (OutOfPages("pool full"), FleetCapacityError, 2),
+    (RuntimeError("RESOURCE_EXHAUSTED: out of device memory"), RuntimeError, 1),
+])
+def test_migration_tries_next_replica_only_on_capacity(dense_pair,
+                                                       monkeypatch, fault,
+                                                       raised, tried):
+    """A destination out of pages or slots is skipped; a device fault
+    (an OOM, a compile error) surfaces instead of reading as "full"."""
+    cfg, tparams, _ = dense_pair
+    router = _mini_router(cfg, tparams, n=3)
+    router.open_session(0, [5, 6, 7, 8], now=0.0)
+    calls = []
+
+    def restore(*a, **k):
+        calls.append(1)
+        raise fault
+
+    for srv in router.verifiers.values():
+        monkeypatch.setattr(srv, "restore_session", restore)
+    with pytest.raises(raised) as info:
+        router.migrate_session(0, [5, 6, 7, 8, 21], rounds=1, now=0.1)
+    assert info.type is raised
+    assert len(calls) == tried          # capacity: both other replicas
 
 
 def test_chaos_straggler_hedged_away(dense_pair, golden_streams):

@@ -4,11 +4,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import backend
 from repro.kernels.logit_features.ops import logit_features_op, logit_features_ref
 from repro.kernels.paged_attention.ops import (
     gather_pages,
     paged_attention_op,
     paged_attention_ref,
+    scatter_kv_pages,
 )
 from repro.kernels.verify_attention.ops import (
     verify_attention_op,
@@ -91,8 +93,8 @@ def test_verify_attention_masking_is_exact():
 def test_paged_attention_sweep(B, Hq, Hkv, D, page, n_pages, n_max, dtype):
     rng = np.random.default_rng(7)
     q = jnp.asarray(rng.normal(size=(B, Hq, D)), dtype)
-    kp = jnp.asarray(rng.normal(size=(n_pages, page, Hkv, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(n_pages, page, Hkv, D)), dtype)
+    kp = jnp.asarray(rng.normal(size=(n_pages, Hkv, page, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(n_pages, Hkv, page, D)), dtype)
     bt = jnp.asarray(
         rng.permutation(n_pages)[: B * n_max].reshape(B, n_max), jnp.int32
     )
@@ -110,8 +112,8 @@ def test_paged_matches_dense_attention():
     rng = np.random.default_rng(3)
     B, H, Hkv, D, page, n_pages, n_max = 2, 4, 2, 64, 128, 6, 3
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, page, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, page, Hkv, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pages, Hkv, page, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, Hkv, page, D)), jnp.float32)
     bt = jnp.asarray([[0, 2, 4], [1, 3, 5]], jnp.int32)
     lengths = jnp.asarray([300, 384], jnp.int32)
     out = paged_attention_op(q, kp, vp, bt, lengths)
@@ -121,6 +123,40 @@ def test_paged_matches_dense_attention():
         q[:, None], kd, vd, lengths
     )[:, 0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def test_scatter_kv_pages_writes_head_major_pages():
+    """Token t of row b lands at [page, head, offset] of the head-major
+    pool; positions past t_lens go to scratch page 0 and nowhere else."""
+    rng = np.random.default_rng(5)
+    n_pages, Hkv, page, D, T = 6, 2, 4, 8, 3
+    kp = jnp.zeros((n_pages, Hkv, page, D), jnp.float32)
+    k_new = jnp.asarray(rng.normal(size=(2, T, Hkv, D)), jnp.float32)
+    bt = jnp.asarray([[3, 5], [2, 4]], jnp.int32)
+    base = jnp.asarray([3, 1], jnp.int32)            # row 0 crosses a page
+    tl = jnp.asarray([3, 2], jnp.int32)              # row 1: last is padding
+    kp2, vp2 = scatter_kv_pages(kp, kp, k_new, -k_new, bt, base, tl)
+    want = np.zeros((n_pages, Hkv, page, D), np.float32)
+    for b, t, pid, off in [(0, 0, 3, 3), (0, 1, 5, 0), (0, 2, 5, 1),
+                           (1, 0, 2, 1), (1, 1, 2, 2)]:
+        want[pid, :, off] = np.asarray(k_new[b, t])
+    got = np.asarray(kp2)
+    np.testing.assert_array_equal(got[1:], want[1:])
+    np.testing.assert_array_equal(np.asarray(vp2)[1:], -want[1:])
+    # the padded token landed on the scratch page, offset 0
+    np.testing.assert_array_equal(got[0, :, 0], np.asarray(k_new[1, 2]))
+
+
+@pytest.mark.parametrize("platform,expected", [
+    ("cpu", True), ("tpu", False), ("gpu", RuntimeError),
+])
+def test_interpret_mode_only_on_cpu(monkeypatch, platform, expected):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if expected is RuntimeError:
+        with pytest.raises(RuntimeError, match="gpu"):
+            backend.interpret()
+    else:
+        assert backend.interpret() is expected
 
 
 # ---------------------------------------------------------------------------

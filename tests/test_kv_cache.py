@@ -269,6 +269,22 @@ def test_free_tokens_accounting():
     assert kv.free_tokens == 7 * 4
 
 
+def test_page_pool_is_head_major():
+    """Pools are (L, n_pages, Hkv, page_size, hd): token t of head h of a
+    page sits at [layer, page, h, t] — the kernel's (page, head) tile is a
+    whole trailing (page_size, hd) block."""
+    kv = _mk_kv(n_pages=8, page_size=4)
+    assert kv.k_pages.shape == (2, 8, 2, 4, 4)
+    kv.open_seq(1, [0])
+    k = np.arange(2 * 3 * 2 * 4, dtype=np.float32).reshape(2, 3, 2, 4)
+    kv.write_tokens(1, 2, jnp.asarray(k), jnp.asarray(-k))
+    p0, p1 = kv.tables[1].pages
+    kp = np.asarray(kv.k_pages)
+    np.testing.assert_array_equal(kp[:, p0, :, 2], k[:, 0])
+    np.testing.assert_array_equal(kp[:, p0, :, 3], k[:, 1])
+    np.testing.assert_array_equal(kp[:, p1, :, 0], k[:, 2])
+
+
 def test_write_and_gather_dense_roundtrip():
     kv = _mk_kv(n_pages=8, page_size=4)
     kv.open_seq(1, [0])

@@ -1,0 +1,121 @@
+"""Compile the serving hot path for a described TPU v5e chip.
+
+Nothing runs: the TPU compiler installed beside JAX lowers and compiles
+each program for ``v5e:2x2``'s first chip from shapes alone, which is what
+interpret-mode tests cannot show (block shapes the Mosaic lowering refuses,
+programs too large for the chip's memory).  The kernel ops pick interpret
+mode from the live backend, so each test steers ``backend.interpret`` to
+the compiled path.  Keep every such compile in this one file: the topology
+is described inside a fixture, by the one worker that runs this file.
+"""
+import dataclasses
+import importlib.util
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import backend
+from repro.kernels.paged_attention.ops import (
+    paged_attention_op,
+    paged_verify_attention_op,
+    scatter_kv_pages,
+)
+from repro.models import transformer
+from repro.serving.engine import fused_paged_verify, fused_paged_verify_specs
+
+HBM_BYTES = 16 * 2**30          # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """Skips only where the TPU compiler is not installed; any other fault
+    in describing the chip fails the tests that need it."""
+    from jax.experimental import topologies
+
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("the TPU compiler (libtpu) is not installed")
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip cannot be read back from the
+    persistent cache without that chip: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def compiled_path(monkeypatch, no_compile_cache):
+    monkeypatch.setattr(backend, "interpret", lambda: False)
+
+
+def _check(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 0 < total < HBM_BYTES
+
+
+# qwen2-7b head geometry: 28 q heads over 4 KV heads (G=7), D=128, P=256
+H, HKV, D, P = 28, 4, 128, 256
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_compiles_for_v5e(one_chip, compiled_path, dtype):
+    B, n_pages, n_max = 16, 33, 8
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    args = (S((B, H, D), dtype), S((n_pages, HKV, P, D), dtype),
+            S((n_pages, HKV, P, D), dtype), S((B, n_max), jnp.int32),
+            S((B,), jnp.int32))
+    _check(jax.jit(paged_attention_op).lower(*args).compile())
+
+
+def test_scatter_kv_pages_compiles_for_v5e(one_chip, compiled_path):
+    """The layer's write-then-read pair: new K/V scattered through the
+    block table into a bf16 pool, then the flattened verify attention."""
+    B, T, n_pages, n_max = 4, 7, 33, 8
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = S((n_pages, HKV, P, D), jnp.bfloat16)
+    new = S((B, T, HKV, D), jnp.float32)
+    lens = S((B,), jnp.int32)
+
+    def write_then_attend(q, kp, vp, k, v, bt, base, tl):
+        kp, vp = scatter_kv_pages(kp, vp, k, v, bt, base, tl)
+        return paged_verify_attention_op(q, kp, vp, bt, base), kp, vp
+
+    compiled = jax.jit(write_then_attend).lower(
+        S((B, T, H, D), jnp.bfloat16), pool, pool, new, new,
+        S((B, n_max), jnp.int32), lens, lens,
+    ).compile()
+    assert "scatter" in compiled.as_text()
+    _check(compiled)
+
+
+def test_fused_paged_verify_compiles_for_v5e(one_chip, compiled_path):
+    """The engine's per-epoch program at qwen2-7b's published widths, cut
+    to 2 layers (depth is a scan: it moves compile time, not shapes)."""
+    cfg = dataclasses.replace(get_config("qwen2-7b-14l"), n_layers=2)
+    specs = fused_paged_verify_specs(
+        cfg, batch=4, k=6, n_pages=17, n_max=4, top_c=64, sharding=one_chip,
+    )
+    program = fused_paged_verify(
+        lambda *a: transformer.decode_paged(cfg, *a)
+    )
+    _check(program.lower(*specs, method="residual", tagged=True).compile())
