@@ -93,13 +93,15 @@ def bench_cell(backend: str, q_mode: str, *, B: int, K: int,
              for _ in range(B)]
     eng.verify(_items(slots, K, q_mode, 0))             # warmup / compile
     base = dict(eng.stats)
+    base_launches = sum(eng.dispatch_counts.values())
     base_verify = eng.dispatch_counts["verify"]
     t0 = time.perf_counter()
     for r in range(1, 1 + epochs):
         eng.verify(_items(slots, K, q_mode, r))
     dt = (time.perf_counter() - t0) / epochs
     d = {k: eng.stats[k] - base[k] for k in
-         ("dispatches", "h2d_bytes", "h2d_q_bytes", "d2h_bytes")}
+         ("h2d_bytes", "h2d_q_bytes", "d2h_bytes")}
+    d["dispatches"] = sum(eng.dispatch_counts.values()) - base_launches
     return {
         "table": "hotpath", "phase": "current", "backend": backend,
         "method": eng.method,

@@ -93,6 +93,9 @@ class WorkItem:
     #: the rate limiter borrowed from the tenant's debt band for this
     #: item — WFQ serves it at a fraction of the tenant's weight
     deprioritized: bool = False
+    #: host clock (``time.perf_counter()``) when a tracing server queued
+    #: the item: the start of its ``wisp.queue`` span; None untraced
+    t_queued: float | None = None
 
     #: kind tag (class attribute, kept for observability and the legacy
     #: ``VerifyRequest(kind=...)`` constructor shim)

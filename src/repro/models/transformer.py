@@ -429,12 +429,14 @@ def _apply_block_paged(
     h = L.rmsnorm(x, bp["ln1"], cfg.norm_eps)
     positions = base_lens[:, None] + jnp.arange(T)[None, :]
     q, k, v = L.qkv_proj(bp["attn"], h, spec, positions)
-    kp_l, vp_l = scatter_kv_pages(
-        kp_l, vp_l, k, v, block_table, base_lens, t_lens
-    )
-    o = paged_verify_attention_op(
-        q, kp_l, vp_l, block_table, base_lens, softcap=spec.softcap
-    )
+    with jax.named_scope("scatter_kv"):
+        kp_l, vp_l = scatter_kv_pages(
+            kp_l, vp_l, k, v, block_table, base_lens, t_lens
+        )
+    with jax.named_scope("paged_attention"):
+        o = paged_verify_attention_op(
+            q, kp_l, vp_l, block_table, base_lens, softcap=spec.softcap
+        )
     att = L.attn_out(bp["attn"], o)
     if cfg.sandwich_norm:
         att = L.rmsnorm(att, bp["ln1_post"], cfg.norm_eps)
@@ -504,11 +506,12 @@ def decode_paged(
             x = _apply_cross_block(cfg, spec, gp["cross"], x, kimg, vimg, ctx=ctx)
             return x, (kpg, vpg)
 
-        x, (kp, vp) = loops.scan(
-            group_body, x,
-            (params["groups"], kp, vp,
-             cross_cache["k_img"], cross_cache["v_img"]),
-        )
+        with jax.named_scope("layer_scan"):
+            x, (kp, vp) = loops.scan(
+                group_body, x,
+                (params["groups"], kp, vp,
+                 cross_cache["k_img"], cross_cache["v_img"]),
+            )
         k_pages = kp.reshape(cfg.n_layers, *kp.shape[2:])
         v_pages = vp.reshape(cfg.n_layers, *vp.shape[2:])
         return _logits(cfg, params, x), (k_pages, v_pages)
@@ -521,7 +524,9 @@ def decode_paged(
         )
         return x, (kpl, vpl)
 
-    x, (k_pages, v_pages) = loops.scan(body, x, (params["blocks"], k_pages, v_pages))
+    with jax.named_scope("layer_scan"):
+        x, (k_pages, v_pages) = loops.scan(
+            body, x, (params["blocks"], k_pages, v_pages))
     return _logits(cfg, params, x), (k_pages, v_pages)
 
 

@@ -34,9 +34,18 @@ leave the device.  Host-side staging uses pooled, bucket-keyed buffers
 buffers' reset state — slot index ``max_slots`` is an out-of-bounds
 sentinel that gathers clamped (read-only) and whose scatter updates XLA
 drops.  ``fed``/``last_token`` commit from one device->host transfer.
-The engine counts compiled-program launches (``dispatch_counts``) and
-staged bytes (``stats``) so benchmarks/hotpath.py and CI can hold the
-dispatch/byte budgets.
+The engine counts compiled-program launches (``dispatch_counts``; the
+first launch of each program and bucket also in
+``stats["cold_dispatches"]``) and staged bytes (``stats``) so
+benchmarks/hotpath.py and CI can hold the dispatch/byte budgets.
+
+With a `Tracer` (``tracer=``, `repro.serving.tracing`) the engine records
+``wisp.verify`` (staging through the result on the host: ``t_verify``)
+with its ``.stage``/``.launch``/``.wait`` children, then
+``wisp.verify.commit``; and ``wisp.prefill`` (``t_chunk``) with
+``wisp.prefill.stage``/``.launch``/``.wait``.  The programs have stable
+names: ``jit_fused_verify`` (paged verify), ``jit_prefill_paged``,
+``jit_dense_verify`` and ``jit_recurrent_verify``.
 
 Batch shapes are padded to fixed buckets (draft length to k_max, batch to
 powers of two) so jit compiles a bounded set of programs.
@@ -54,7 +63,6 @@ deadline-critical verification instead of stalling behind it.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import Counter
 from functools import partial
 
@@ -77,6 +85,7 @@ from repro.serving.kv_cache import (
     PagedKV,
     TierConfig,
 )
+from repro.serving.tracing import span, timed
 
 #: families whose self-attn KV can be paged; recurrent state cannot.
 ATTENTION_FAMILIES = ("dense", "moe", "vlm", "audio")
@@ -113,21 +122,35 @@ def _bucket(n: int, lo: int = 1) -> int:
 def fused_paged_verify(decode_raw):
     """The paged backend's fused verify program: ``decode_raw`` (a paged
     decode with the config bound) over the verify block, then the accept
-    rule — one jit dispatch returning ``(accept_len, token, (k_pages,
-    v_pages))``.  The page pools (args 2/3) are donated so XLA updates
-    them in place instead of copying the whole pool every epoch."""
-    def fused(params, feed, kp, vp, bt, base, tl, cross, draft, dlen,
-              rng, tags, qargs, *, method, tagged):
+    rule — one jit dispatch (module ``jit_fused_verify``) returning
+    ``(accept_len, token, (k_pages, v_pages))``.  The page pools (args
+    2/3) are donated so XLA updates them in place instead of copying the
+    whole pool every epoch."""
+    def fused_verify(params, feed, kp, vp, bt, base, tl, cross, draft, dlen,
+                     rng, tags, qargs, *, method, tagged):
         logits, (kp, vp) = decode_raw(params, feed, kp, vp, bt, base,
                                       tl, cross)
-        out = verify_epoch_rule(
-            rng, draft, dlen, logits, method=method,
-            rng_tags=tags if tagged else None, **qargs,
-        )
+        with jax.named_scope("accept_rule"):
+            out = verify_epoch_rule(
+                rng, draft, dlen, logits, method=method,
+                rng_tags=tags if tagged else None, **qargs,
+            )
         return out["accept_len"], out["token"], (kp, vp)
 
-    return jax.jit(fused, static_argnames=("method", "tagged"),
+    return jax.jit(fused_verify, static_argnames=("method", "tagged"),
                    donate_argnums=(2, 3))
+
+
+def paged_prefill(decode_raw):
+    """The paged backend's prefill program (module ``jit_prefill_paged``):
+    ``decode_raw`` over a batch of prompt chunks, returning ``(logits,
+    (k_pages, v_pages))``.  The page pools (args 2/3) are donated so XLA
+    updates pages in place instead of copying the whole pool (and
+    transiently doubling KV memory) every call; the CPU ignores it."""
+    def prefill_paged(params, feed, kp, vp, bt, base, tl, cross):
+        return decode_raw(params, feed, kp, vp, bt, base, tl, cross)
+
+    return jax.jit(prefill_paged, donate_argnums=(2, 3))
 
 
 def fused_paged_verify_specs(cfg, *, batch: int, k: int, n_pages: int,
@@ -191,7 +214,10 @@ class VerifyOutcome:
     accept_len: int
     token: int                   # correction / bonus token
     emitted: int                 # accept_len + 1
-    t_verify: float              # engine wall time attributed to the batch
+    #: host seconds of the batch's ``wisp.verify`` span: staging, launch
+    #: and the wait for the results.  The wait also covers any prefill
+    #: program still running on the device from the step before.
+    t_verify: float
 
 
 @dataclasses.dataclass
@@ -243,7 +269,10 @@ class PrefillOutcome:
     done: int                    # total valid prompt tokens after the chunk
     total: int                   # prompt length
     first_token: int | None      # set when the prompt completed this chunk
-    t_chunk: float               # engine wall time attributed to the chunk
+    #: host seconds of the pass's ``wisp.prefill`` span: staging and the
+    #: asynchronous launch (not the program's run), plus the wait for the
+    #: first tokens when a prompt finished in the pass
+    t_chunk: float
     oom: bool = False            # chunk deferred: page pool cannot cover it
 
 
@@ -265,8 +294,11 @@ class VerificationEngine:
         spill_quantize: bool = False,
         spill_idle_epochs: int = 2,
         device=None,
+        tracer=None,
     ):
         self.cfg = cfg
+        #: `repro.serving.tracing.Tracer` or None (no spans recorded)
+        self.tracer = tracer
         #: the device holding this engine's pools, staging and rng keys
         #: (None: JAX's default device); params must already live there
         self.device = device
@@ -298,6 +330,8 @@ class VerificationEngine:
         #: compiled-program launches by name ("verify" is the fused
         #: per-epoch program — exactly one per verify() call, any backend)
         self.dispatch_counts: Counter = Counter()
+        #: (program, bucket) keys launched so far (``cold_dispatches``)
+        self._launched: set = set()
         #: ``prefix_cached_tokens`` counts prompt tokens satisfied by the
         #: content-addressed prefix cache.  That cache exists only on the
         #: paged backend — on the dense backend the field is structurally
@@ -311,7 +345,10 @@ class VerificationEngine:
             "tokens_committed": 0,
             "prefix_cached_tokens": 0,
             "prefill_chunks": 0,
-            "dispatches": 0,          # compiled-program launches
+            #: launches of a (program, bucket) not launched before: each
+            #: compiled or loaded a program, unless an earlier engine in
+            #: the process had
+            "cold_dispatches": 0,
             "h2d_bytes": 0,           # host->device staged bytes (verify)
             "h2d_q_bytes": 0,         # ...of which draft-q payload
             "d2h_bytes": 0,           # device->host result bytes (verify)
@@ -378,10 +415,6 @@ class VerificationEngine:
         self.share_prefix = cfg.family in ("dense", "moe")
         self.tokens: dict[int, list] = {}   # slot -> tokens with KV in pages
         self.extras_cache = None
-        # donate the page pool (args 2/3 after params, tokens) so XLA
-        # updates pages in place instead of copying the whole pool (and
-        # transiently doubling KV memory) every call; CPU ignores it
-        _jit = partial(jax.jit, donate_argnums=(2, 3))
         if cfg.family == "vlm":
             n_groups = cfg.n_layers // cfg.cross_attn_every
             z = lambda: jnp.zeros(
@@ -392,7 +425,7 @@ class VerificationEngine:
             self._extras_key = "image_embeds"
             self._extras_builder = jax.jit(partial(transformer.vlm_cross_kv, cfg))
             decode_raw = partial(transformer.decode_paged, cfg)
-            self._prefill_paged = _jit(
+            self._prefill_paged = paged_prefill(
                 partial(transformer.decode_paged, cfg, dropless=False)
             )
         elif cfg.family == "audio":
@@ -404,12 +437,12 @@ class VerificationEngine:
             self._extras_key = "frames"
             self._extras_builder = jax.jit(partial(encdec.encdec_cross_kv, cfg))
             decode_raw = partial(encdec.encdec_decode_paged, cfg)
-            self._prefill_paged = _jit(decode_raw)       # no MoE routing
+            self._prefill_paged = paged_prefill(decode_raw)  # no MoE routing
         else:
             decode_raw = partial(transformer.decode_paged, cfg)
             # prompt prefill keeps GShard capacity MoE routing, matching
             # the dense `prefill` path (verify stays dropless)
-            self._prefill_paged = _jit(
+            self._prefill_paged = paged_prefill(
                 partial(transformer.decode_paged, cfg, dropless=False)
             )
         self._fused_verify = fused_paged_verify(decode_raw)
@@ -425,18 +458,19 @@ class VerificationEngine:
         decode = self.bundle.decode
         bax = self._bax
 
-        def fused(params, cache, slot_idx, feed, pos, draft, dlen, rng,
-                  tags, qargs, *, method, tagged):
+        def dense_verify(params, cache, slot_idx, feed, pos, draft, dlen,
+                         rng, tags, qargs, *, method, tagged):
             sub = jax.tree.map(
                 lambda leaf, ax: jnp.take(leaf, slot_idx, axis=ax,
                                           mode="clip"),
                 cache, bax,
             )
             p_logits, sub = decode(params, feed, sub, pos)
-            out = verify_epoch_rule(
-                rng, draft, dlen, p_logits, method=method,
-                rng_tags=tags if tagged else None, **qargs,
-            )
+            with jax.named_scope("accept_rule"):
+                out = verify_epoch_rule(
+                    rng, draft, dlen, p_logits, method=method,
+                    rng_tags=tags if tagged else None, **qargs,
+                )
 
             def put(leaf, new, ax):
                 sl = (slice(None),) * ax
@@ -447,7 +481,7 @@ class VerificationEngine:
             cache = jax.tree.map(put, cache, sub, bax)
             return out["accept_len"], out["token"], cache
 
-        return jax.jit(fused, static_argnames=("method", "tagged"),
+        return jax.jit(dense_verify, static_argnames=("method", "tagged"),
                        donate_argnums=(1,))
 
     def _build_fused_recurrent(self):
@@ -463,8 +497,8 @@ class VerificationEngine:
 
             return jax.tree.map(w, new, old, bax)
 
-        def fused(params, cache, slot_idx, feed, pos, draft, dlen, rng,
-                  tags, qargs, *, method, tagged):
+        def recurrent_verify(params, cache, slot_idx, feed, pos, draft, dlen,
+                             rng, tags, qargs, *, method, tagged):
             B, T = feed.shape
             K = T - 1
             sub = jax.tree.map(
@@ -576,7 +610,7 @@ class VerificationEngine:
             cache = jax.tree.map(put, cache, kept, bax)
             return L, token.astype(jnp.int32), cache
 
-        return jax.jit(fused, static_argnames=("method", "tagged"),
+        return jax.jit(recurrent_verify, static_argnames=("method", "tagged"),
                        donate_argnums=(1,))
 
     def _put(self, x, dtype=None):
@@ -617,9 +651,11 @@ class VerificationEngine:
         )
 
     # -- staging pools + dispatch accounting ----------------------------------
-    def _count_dispatch(self, name: str):
+    def _count_dispatch(self, name: str, bucket: tuple = ()):
         self.dispatch_counts[name] += 1
-        self.stats["dispatches"] += 1
+        if (name, bucket) not in self._launched:
+            self._launched.add((name, bucket))
+            self.stats["cold_dispatches"] += 1
 
     def _pool(self, key: tuple, spec: dict) -> dict:
         """Fetch (or build) the pooled buffer set for ``key``.  On reuse,
@@ -781,10 +817,9 @@ class VerificationEngine:
             self.free_slots.append(slot)
             raise
         if self.extras_cache is not None:
-            self._count_dispatch("extras")
-            k_x, v_x = self._extras_builder(
-                self.params, self._put(extras[self._extras_key])
-            )
+            x = extras[self._extras_key]
+            self._count_dispatch("extras", np.shape(x))
+            k_x, v_x = self._extras_builder(self.params, self._put(x))
             keys = sorted(self.extras_cache)          # (k_img, v_img) / (k_mem, v_mem)
             self._extras_put(slot, {keys[0]: k_x, keys[1]: v_x})
         return PrefillState(slot=slot, tokens=toks, done=n_cached,
@@ -829,14 +864,11 @@ class VerificationEngine:
             self.tokens[slot] = [int(t) for t in st.tokens]
             self.stats["prefix_cached_tokens"] += int(st.n_cached)
 
-    def _prefill_chunks_paged(self, chunks, *, raise_oom: bool = False):
-        """Execute prefill chunks as rows of ONE ragged ``decode_paged``
-        call (the flattened multi-token paged path verification uses — each
-        prompt token is its own kernel row with length ``done + t + 1``, so
-        chunked and monolithic prefill run the identical per-token
-        computation).  Returns per-chunk ``oom`` flags; with ``raise_oom``
-        an uncoverable chunk raises instead.  Either way the affected
-        state is untouched and resumable."""
+    def _stage_prefill_chunks(self, chunks, raise_oom: bool) -> tuple:
+        """Page reservation, pooled staging and the device puts of one
+        ragged prefill pass.  Returns the per-chunk ``oom`` flags, the live
+        ``(state, n)`` rows, the program bucket and its arguments after the
+        params and pools."""
         live: list = []
         oom = [False] * len(chunks)
         if self.tiered:
@@ -864,7 +896,7 @@ class VerificationEngine:
                 continue
             live.append((st, n))
         if not live:
-            return oom
+            return oom, live, None, None
         T = _bucket(max(n for _, n in live), 16)
         nb = _bucket(len(live), 1)
         n_max = _bucket(max(self.kv.seq_pages(st.slot) for st, _ in live), 1)
@@ -895,18 +927,31 @@ class VerificationEngine:
             self._extras_gather(staged["slots"])
             if self.extras_cache is not None else None
         )
-        self._count_dispatch("prefill")
-        logits, (kp, vp) = self._prefill_paged(
-            self.params,
-            self._put(staged["feed"]),
-            self.kv.k_pages,
-            self.kv.v_pages,
-            self._put(staged["bt"]),
-            self._put(staged["base"]),
-            self._put(staged["tl"]),
-            cross,
-        )
-        self.kv.k_pages, self.kv.v_pages = kp, vp
+        args = [self._put(staged[k]) for k in ("feed", "bt", "base", "tl")]
+        return oom, live, (nb, T, n_max), args + [cross]
+
+    def _prefill_chunks_paged(self, chunks, *, raise_oom: bool = False):
+        """Execute prefill chunks as rows of ONE ragged ``decode_paged``
+        call (the flattened multi-token paged path verification uses — each
+        prompt token is its own kernel row with length ``done + t + 1``, so
+        chunked and monolithic prefill run the identical per-token
+        computation).  Returns per-chunk ``oom`` flags; with ``raise_oom``
+        an uncoverable chunk raises instead.  Either way the affected
+        state is untouched and resumable."""
+        tr = self.tracer
+        with span(tr, "wisp.prefill.stage", chunks=len(chunks)):
+            oom, live, bucket, args = self._stage_prefill_chunks(chunks,
+                                                                 raise_oom)
+        if not live:
+            return oom
+        with span(tr, "wisp.prefill.launch", bucket=bucket):
+            self._count_dispatch("prefill", bucket)
+            feed_d, bt_d, base_d, tl_d, cross = args
+            logits, (kp, vp) = self._prefill_paged(
+                self.params, feed_d, self.kv.k_pages, self.kv.v_pages,
+                bt_d, base_d, tl_d, cross,
+            )
+            self.kv.k_pages, self.kv.v_pages = kp, vp
         finished: list = []
         for i, (st, n) in enumerate(live):
             st.done += n
@@ -919,13 +964,16 @@ class VerificationEngine:
             # one device-side argmax + ONE transfer for every chunk that
             # completed its prompt this call (was: a blocking
             # int(jnp.argmax(...)) sync per finished row)
-            ridx = self._put([i for i, _, _ in finished], jnp.int32)
-            cpos = self._put([n - 1 for _, n, _ in finished], jnp.int32)
-            firsts = np.asarray(
-                jax.device_get(jnp.argmax(logits[ridx, cpos], axis=-1))
-            )
-            for (_, _, st), first in zip(finished, firsts):
-                self._finish_prefill(st, int(first))
+            with span(tr, "wisp.prefill.wait", finished=len(finished)):
+                self._count_dispatch("first_token",
+                                     bucket[:2] + (len(finished),))
+                ridx = self._put([i for i, _, _ in finished], jnp.int32)
+                cpos = self._put([n - 1 for _, n, _ in finished], jnp.int32)
+                firsts = np.asarray(
+                    jax.device_get(jnp.argmax(logits[ridx, cpos], axis=-1))
+                )
+                for (_, _, st), first in zip(finished, firsts):
+                    self._finish_prefill(st, int(first))
         return oom
 
     def _prefill_chunk_dense(self, st: PrefillState, n: int):
@@ -951,10 +999,10 @@ class VerificationEngine:
             batch = {"tokens": self._put(padded)}
             if st.extras:
                 batch.update(st.extras)
-            self._count_dispatch("prefill")
+            self._count_dispatch("prefill", (Tb, True))
             logits, sub = self._prefill(self.params, batch, sub)
         else:
-            self._count_dispatch("prefill")
+            self._count_dispatch("prefill", (Tb, False))
             logits, sub = self._decode(
                 self.params, self._put(padded), sub, jnp.int32(s0)
             )
@@ -963,6 +1011,7 @@ class VerificationEngine:
         st.chunks += 1
         self.stats["prefill_chunks"] += 1
         if st.remaining == 0:
+            self._count_dispatch("first_token", (Tb, n))
             first = int(jax.device_get(jnp.argmax(logits[0, n - 1])))
             self._finish_prefill(st, first)
 
@@ -1004,19 +1053,20 @@ class VerificationEngine:
         out: list = [None] * len(items)
         for i, o in zip(vidx, self.verify([items[i] for i in vidx])):
             out[i] = o
-        t0 = time.perf_counter()        # the verify wall time is not the chunks'
         if cidx:
             chunks = [items[i] for i in cidx]
             before = [c.state.done for c in chunks]
-            if self.paged:
-                oom = self._prefill_chunks_paged(chunks)
-            else:
-                oom = [False] * len(chunks)
-                for c in chunks:
-                    self._prefill_chunk_dense(
-                        c.state, min(int(c.n_tokens), c.state.remaining)
-                    )
-            dt = time.perf_counter() - t0
+            # t_chunk: the whole prefill pass, not the verify before it
+            with timed(self.tracer, "wisp.prefill") as tp:
+                if self.paged:
+                    oom = self._prefill_chunks_paged(chunks)
+                else:
+                    oom = [False] * len(chunks)
+                    for c in chunks:
+                        self._prefill_chunk_dense(
+                            c.state, min(int(c.n_tokens), c.state.remaining)
+                        )
+            dt = tp.dt
             for i, c, was, o in zip(cidx, chunks, before, oom):
                 st = c.state
                 out[i] = PrefillOutcome(
@@ -1037,7 +1087,26 @@ class VerificationEngine:
         transfer, commit ``fed``/``last_token`` vectorized."""
         if not items:
             return []
-        t0 = time.perf_counter()
+        tr = self.tracer
+        # t_verify: staging through the results on the host
+        with timed(tr, "wisp.verify") as tv:
+            with span(tr, "wisp.verify.stage"):
+                bufs, bucket, launch = self._stage_verify_batch(items)
+            with span(tr, "wisp.verify.launch", bucket=bucket):
+                self._count_dispatch("verify", bucket)
+                acc_d, tok_d = launch()
+            with span(tr, "wisp.verify.wait"):
+                # ONE device->host transfer carries the whole epoch's results
+                acc, tok = jax.device_get((acc_d, tok_d))
+            self.stats["d2h_bytes"] += acc.nbytes + tok.nbytes
+        with span(tr, "wisp.verify.commit"):
+            return self._commit_verify(items, bufs, acc, tok, tv.dt)
+
+    def _stage_verify_batch(self, items: list[VerifyItem]) -> tuple:
+        """Pooled staging, page reservation (``OutOfPages`` raises here,
+        before any engine side effect), rng, block tables and the device
+        puts of one verify batch.  Returns its buffers, its program bucket
+        and the call that launches the fused program."""
         n = len(items)
         dlens = {len(it.draft_tokens) for it in items}
         if len(dlens) > 1:
@@ -1131,23 +1200,47 @@ class VerificationEngine:
         tags_d = self._put(bufs["tags"])
 
         if self.paged:
-            acc_d, tok_d = self._dispatch_verify_paged(
-                items, bufs, feed_d, draft_d, dlen_d, kv, tags_d, tagged,
-                qargs, n, nb,
+            n_max = _bucket(max(self.kv.seq_pages(it.slot) for it in items),
+                            1)
+            btb = self._pool(("bt", nb, n_max), {
+                "bt": ((nb, n_max), np.int32, 0),
+            })
+            btb["bt"][:n] = self.kv.block_table([it.slot for it in items],
+                                                n_max)
+            btb["_hw"] = n
+            self.stats["h2d_bytes"] += btb["bt"].nbytes + bufs["tl"].nbytes
+            cross = (
+                self._extras_gather(bufs["slots"])
+                if self.extras_cache is not None else None
             )
-        else:
-            self._count_dispatch("verify")
-            acc_d, tok_d, self.cache = self._fused_verify(
-                self.params, self.cache, self._put(bufs["slots"]),
-                feed_d, self._put(bufs["pos"]), draft_d, dlen_d,
-                kv, tags_d, qargs, method=self.method, tagged=tagged,
-            )
-        # ONE device->host transfer carries the whole epoch's results
-        acc, tok = jax.device_get((acc_d, tok_d))
-        self.stats["d2h_bytes"] += acc.nbytes + tok.nbytes
-        dt = time.perf_counter() - t0
+            bt_d, pos_d, tl_d = (self._put(btb["bt"]), self._put(bufs["pos"]),
+                                 self._put(bufs["tl"]))
 
-        # batched commit: fed/last_token advance for all rows at once
+            def launch():
+                acc_d, tok_d, (kp, vp) = self._fused_verify(
+                    self.params, feed_d, self.kv.k_pages, self.kv.v_pages,
+                    bt_d, pos_d, tl_d, cross, draft_d, dlen_d,
+                    kv, tags_d, qargs, method=self.method, tagged=tagged,
+                )
+                self.kv.k_pages, self.kv.v_pages = kp, vp
+                return acc_d, tok_d
+
+            return bufs, (nb, K, n_max, q_kind, C, tagged), launch
+        slots_d, pos_d = self._put(bufs["slots"]), self._put(bufs["pos"])
+
+        def launch():
+            acc_d, tok_d, self.cache = self._fused_verify(
+                self.params, self.cache, slots_d, feed_d, pos_d, draft_d,
+                dlen_d, kv, tags_d, qargs, method=self.method, tagged=tagged,
+            )
+            return acc_d, tok_d
+
+        return bufs, (nb, K, q_kind, C, tagged), launch
+
+    def _commit_verify(self, items, bufs, acc, tok, t_verify: float) -> list:
+        """Advance ``fed``/``last_token`` for all rows at once, then each
+        row's tokens, length pointer and tail pages."""
+        n = len(items)
         sl = bufs["slots"][:n].astype(np.int64)
         accs = np.asarray(acc[:n], np.int64)
         toks = np.asarray(tok[:n], np.int64)
@@ -1172,37 +1265,10 @@ class VerificationEngine:
                     accept_len=L,
                     token=int(toks[i]),
                     emitted=L + 1,
-                    t_verify=dt,
+                    t_verify=t_verify,
                 )
             )
         self.stats["batches"] += 1
         self.stats["tokens_verified"] += int(bufs["dlen"][:n].sum())
         self.stats["tokens_committed"] += int(accs.sum()) + n
         return results
-
-    # -- paged-target verification ---------------------------------------------
-    def _dispatch_verify_paged(self, items, bufs, feed_d, draft_d, dlen_d,
-                               kv, tags_d, tagged, qargs, n, nb):
-        """Stage block tables and launch the fused paged program.  Page
-        capacity was already reserved by ``verify`` (OutOfPages raises
-        there, before any engine side effect)."""
-        n_max = _bucket(max(self.kv.seq_pages(it.slot) for it in items), 1)
-        btb = self._pool(("bt", nb, n_max), {
-            "bt": ((nb, n_max), np.int32, 0),
-        })
-        btb["bt"][:n] = self.kv.block_table([it.slot for it in items], n_max)
-        btb["_hw"] = n
-        self.stats["h2d_bytes"] += btb["bt"].nbytes + bufs["tl"].nbytes
-        cross = (
-            self._extras_gather(bufs["slots"])
-            if self.extras_cache is not None else None
-        )
-        self._count_dispatch("verify")
-        acc_d, tok_d, (kp, vp) = self._fused_verify(
-            self.params, feed_d, self.kv.k_pages, self.kv.v_pages,
-            self._put(btb["bt"]), self._put(bufs["pos"]),
-            self._put(bufs["tl"]), cross, draft_d, dlen_d,
-            kv, tags_d, qargs, method=self.method, tagged=tagged,
-        )
-        self.kv.k_pages, self.kv.v_pages = kp, vp
-        return acc_d, tok_d

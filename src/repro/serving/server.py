@@ -36,6 +36,7 @@ latency model at thousands of devices.
 from __future__ import annotations
 
 import dataclasses
+import time
 import warnings
 from collections import deque
 
@@ -62,6 +63,7 @@ from repro.serving.events import (
     VerdictEvent,
 )
 from repro.serving.kv_cache import OutOfPages
+from repro.serving.tracing import span
 from repro.serving.transport import NetworkModel
 from repro.tenancy import DEFAULT_TENANT, Stage, TenantRegistry
 
@@ -230,6 +232,15 @@ class AdmissionQueue:
         return (e for t, e in self._q if t not in self._dead)
 
 
+def _work_id(r) -> tuple:
+    """``(session_id, round_index)`` of a verify block; of a prefill chunk
+    that has run, ``(session_id, chunk index)``: joins a ``wisp.queue``
+    interval to the ``wisp.step`` that served it."""
+    if r.kind == "prefill":
+        return r.session_id, r.payload.state.chunks - 1
+    return r.session_id, r.round_index
+
+
 class WISPServer:
     def __init__(
         self,
@@ -247,8 +258,13 @@ class WISPServer:
         prefill_chunk_tokens: int = 256,
         ttft_slo: dict | None = None,
         tenants=None,   # TenantRegistry | iterable of TenantSpec / spec str
+        tracer=None,    # repro.serving.tracing.Tracer: spans of each step
     ):
         self.engine = engine
+        #: one tracer for the server and its engine (the engine's own if
+        #: the server is given none); None records nothing
+        self.tracer = engine.tracer if tracer is None else tracer
+        engine.tracer = self.tracer
         self.coeffs = coeffs
         self.sched_cfg = sched_cfg or SchedulerConfig()
         if scheduler is not None:
@@ -550,6 +566,7 @@ class WISPServer:
             payload=ps,
             prefill_tokens=min(self.prefill_chunk_tokens, st.remaining),
             enqueued_at=now,
+            t_queued=None if self.tracer is None else time.perf_counter(),
             tenant=ps.tenant,
             tenant_weight=self.tenants.weight(ps.tenant),
             deprioritized=ps.deprioritized,
@@ -828,6 +845,7 @@ class WISPServer:
                 q_compact,
             ),
             enqueued_at=now,
+            t_queued=None if self.tracer is None else time.perf_counter(),
             round_index=s.rounds,
             pagein_tokens=self.engine.spilled_tokens(s.slot),
             tenant=s.tenant,
@@ -907,29 +925,57 @@ class WISPServer:
         to this epoch.  The event-driven cluster runtime passes one driven
         by the estimator (+ optional noise) so queueing/violation accounting
         runs on the virtual clock; by default each verdict carries the
-        engine's measured wall time (synchronous CPU drivers)."""
+        engine's measured wall time (synchronous CPU drivers).
+
+        With a tracer the epoch is a ``wisp.step`` span whose children
+        are ``wisp.admit``, ``wisp.schedule``, the engine's spans and
+        ``wisp.apply``; each item served gets a ``wisp.queue`` interval
+        from when it was queued to the step's start."""
+        tr = self.tracer
+        if tr is not None:
+            tr.epoch += 1
+            cold0 = self.engine.stats["cold_dispatches"]
+        with span(tr, "wisp.step") as sp:
+            verdicts = self._step(now, verify_time, tr)
+            if tr is not None:
+                served = self.last_served
+                sp.set(ids=[_work_id(r) for r in served],
+                       verify_rows=sum(r.kind == "verify" for r in served),
+                       prefill_rows=sum(r.kind == "prefill" for r in served),
+                       cold=self.engine.stats["cold_dispatches"] - cold0)
+                for r in served:
+                    tr.interval("wisp.queue", r.t_queued, sp.t0,
+                                _work_id(r), kind=r.kind)
+        return verdicts
+
+    def _step(self, now, verify_time, tr) -> list[Verdict]:
         self.now = max(self.now, now)
-        self._release_throttled(now)
-        self._try_admit()
-        # M(t_k): live free-page capacity, not a static config number
-        self.memory_budget_tokens = (
-            self.engine.memory_budget_tokens()
-            if self.dynamic_memory_budget
-            else self.sched_cfg.memory_budget_tokens
-        )
+        with span(tr, "wisp.admit") as sa:
+            live0 = len(self.sessions) + len(self.prefilling)
+            self._release_throttled(now)
+            self._try_admit()
+            # M(t_k): live free-page capacity, not a static config number
+            self.memory_budget_tokens = (
+                self.engine.memory_budget_tokens()
+                if self.dynamic_memory_budget
+                else self.sched_cfg.memory_budget_tokens
+            )
+            sa.set(admitted=len(self.sessions) + len(self.prefilling) - live0)
         self.last_served = []
         if not self.pending:
             return []
-        decision = self.scheduler.schedule(
-            self.pending, now, memory_budget_tokens=self.memory_budget_tokens
-        )
-        self.last_decision = decision
-        if not decision.batch:
-            return []
-        chosen = {r.req_id for r in decision.batch}
-        self.pending = [r for r in self.pending if r.req_id not in chosen]
-
-        items = [r.make_engine_item(self) for r in decision.batch]
+        with span(tr, "wisp.schedule", pending=len(self.pending)) as ss:
+            decision = self.scheduler.schedule(
+                self.pending, now,
+                memory_budget_tokens=self.memory_budget_tokens
+            )
+            self.last_decision = decision
+            ss.set(chosen=len(decision.batch))
+            if not decision.batch:
+                return []
+            chosen = {r.req_id for r in decision.batch}
+            self.pending = [r for r in self.pending if r.req_id not in chosen]
+            items = [r.make_engine_item(self) for r in decision.batch]
         try:
             served = list(decision.batch)
             outcomes = self.engine.step(items)
@@ -947,7 +993,12 @@ class WISPServer:
                     served.append(r)
                 except OutOfPages:
                     self.pending.append(r)
+        with span(tr, "wisp.apply"):
+            return self._apply(served, outcomes, now, verify_time)
 
+    def _apply(self, served, outcomes, now, verify_time) -> list[Verdict]:
+        """Requeue deferred work, then commit each outcome (verdicts,
+        first tokens and their events)."""
         # work the engine deferred (e.g. prefill chunks the page pool could
         # not cover — state untouched) requeues like the OutOfPages verify
         # path above
