@@ -10,12 +10,20 @@ Two consumers:
     serves both paths and the block table stays the only addressing
     structure (DESIGN.md §2).
 
-``scatter_kv_pages`` is the functional write path: new K/V land in the
-pages named by the block table; masked/padded positions are redirected to
-the reserved scratch page 0 so they can never clobber live pages.
+``scatter_kv_pages`` is the write path: new K/V land in the pages named
+by the block table; masked/padded positions are redirected to the reserved
+scratch page 0 so they can never clobber live pages.
+
+``write_and_attend`` is one layer of a paged layer scan.  The scan carries
+the stacked pools as one flat pool ``(n_layers * n_pages, Hkv, P, D)`` (a
+bitcast of ``(n_layers, n_pages, ...)``) and layer ``l`` addresses its
+pages as ``block_table + l * n_pages``, so the pools are written and read
+in place: no layer's slice is cut out of the stack, copied or re-laid out
+(DESIGN.md §2).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import backend
@@ -61,35 +69,77 @@ def paged_verify_attention_op(
 
 
 def scatter_kv_pages(
-    k_pages,           # (n_pages, Hkv, P, D) one layer's pages
+    k_pages,           # (n_pages, Hkv, P, D) pages, of one layer or flat
     v_pages,
     k_new,             # (B, T, Hkv, D) K/V of the new tokens
     v_new,
-    block_table,       # (B, n_max) int32
+    block_table,       # (B, n_max) int32 page ids within the layer
     base_lens,         # (B,) int32 write offset (committed kv tokens)
     t_lens,            # (B,) int32 valid new tokens per row (<= T)
+    page_offset=0,     # int32 scalar: the layer's first page in a flat pool
 ):
-    """Write new K/V through the block table (functional scatter).
+    """Write new K/V through the block table.
 
-    Row b token t lands at page block_table[b, (base+t)//P], offset
-    (base+t)%P.  Positions past t_lens[b] (draft-length padding, padded
-    batch rows) are redirected to scratch page 0: distinct live rows write
-    disjoint pages, so the only scatter collisions are garbage-on-garbage
-    inside the scratch page."""
-    P = k_pages.shape[2]
+    Row b token t lands at page page_offset + block_table[b, (base+t)//P],
+    offset (base+t)%P.  Positions past t_lens[b] (draft-length padding,
+    padded batch rows) are redirected to the layer's scratch page
+    page_offset + 0: distinct live rows write disjoint pages, so the only
+    scatter collisions are garbage-on-garbage inside the scratch page."""
+    Hkv, P = k_pages.shape[1:3]
     B, T = k_new.shape[:2]
     n_max = block_table.shape[1]
     pos = base_lens[:, None] + jnp.arange(T)[None, :]               # (B, T)
     valid = jnp.arange(T)[None, :] < t_lens[:, None]                # (B, T)
     slot = jnp.clip(pos // P, 0, n_max - 1)
     pid = jnp.take_along_axis(block_table, slot, axis=1)            # (B, T)
-    pid = jnp.where(valid, pid, 0)
+    pid = jnp.where(valid, pid, 0) + page_offset
     off = jnp.where(valid, pos % P, 0)
-    # (pid, :, off) indexes a (B, T, Hkv, D) view: the advanced indices
-    # split by the head slice broadcast to the front, matching k_new
-    k_pages = k_pages.at[pid, :, off].set(k_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[pid, :, off].set(v_new.astype(v_pages.dtype))
+    # an explicit head index, broadcast to (B, T, Hkv), addresses the
+    # update as it comes, (B, T, Hkv, D): with a head slice between the
+    # two advanced indices the compiler re-lays the pages out around the
+    # scatter
+    idx = (pid[..., None], jnp.arange(Hkv)[None, None, :], off[..., None])
+    k_pages = k_pages.at[idx].set(k_new.astype(k_pages.dtype))
+    v_pages = v_pages.at[idx].set(v_new.astype(v_pages.dtype))
     return k_pages, v_pages
+
+
+def flat_pools(k_pages, v_pages):
+    """``(n_layers, n_pages, ...)`` pools as one flat page pool each,
+    ``(n_layers * n_pages, ...)``: a bitcast, not a copy."""
+    flat = lambda p: p.reshape(p.shape[0] * p.shape[1], *p.shape[2:])
+    return flat(k_pages), flat(v_pages)
+
+
+def write_and_attend(
+    q,                 # (B, T, H, D) queries of the new tokens
+    k_new,             # (B, T, Hkv, D)
+    v_new,
+    k_flat,            # (n_layers * n_pages, Hkv, P, D) flat pools
+    v_flat,
+    layer,             # int32 scalar: the layer's index in the stack
+    n_pages: int,      # pages per layer
+    block_table,       # (B, n_max) int32 page ids within the layer
+    base_lens,         # (B,) int32
+    t_lens,            # (B,) int32
+    *,
+    softcap: float = 0.0,
+):
+    """One layer of a paged layer scan: scatter the new K/V into the
+    layer's pages of the flat pools, then attend from them there.
+    Returns ``(o, k_flat, v_flat)``."""
+    page_offset = layer * n_pages
+    with jax.named_scope("scatter_kv"):
+        k_flat, v_flat = scatter_kv_pages(
+            k_flat, v_flat, k_new, v_new, block_table, base_lens, t_lens,
+            page_offset,
+        )
+    with jax.named_scope("paged_attention"):
+        o = paged_verify_attention_op(
+            q, k_flat, v_flat, block_table + page_offset, base_lens,
+            softcap=softcap,
+        )
+    return o, k_flat, v_flat
 
 
 __all__ = [
@@ -98,5 +148,7 @@ __all__ = [
     "paged_verify_attention_op",
     "paged_verify_attention_ref",
     "scatter_kv_pages",
+    "flat_pools",
+    "write_and_attend",
     "gather_pages",
 ]

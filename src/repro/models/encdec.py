@@ -15,6 +15,7 @@ from repro.common import loops
 
 from repro.common.sharding import NULL_CTX
 from repro.configs.base import ArchConfig
+from repro.kernels.paged_attention.ops import flat_pools, write_and_attend
 from repro.models import layers as L
 from repro.models.transformer import _stack_init, _stack_axes, attn_spec
 
@@ -255,45 +256,43 @@ def encdec_decode_paged(
     ctx=NULL_CTX,
 ):
     """Paged analogue of ``encdec_decode``: decoder self-attention runs over
-    `PagedKV` pages; cross-attention memory stays dense (bounded by
-    encoder_frames, not part of the growing KV)."""
-    from repro.kernels.paged_attention.ops import (
-        paged_verify_attention_op,
-        scatter_kv_pages,
-    )
-
+    `PagedKV` pages, written in place through flat pools as in
+    ``transformer.decode_paged``; cross-attention memory stays dense
+    (bounded by encoder_frames, not part of the growing KV)."""
     spec = _spec(cfg)
     base_lens = jnp.asarray(base_lens, jnp.int32)
     t_lens = jnp.asarray(t_lens, jnp.int32)
     block_table = jnp.asarray(block_table, jnp.int32)
     x = _embed_tokens(cfg, params, tokens, base_lens)
     T = tokens.shape[1]
+    n_layers, n_pages = k_pages.shape[:2]
+    kf, vf = flat_pools(k_pages, v_pages)
 
-    def body(x, inp):
-        bp, kpl, vpl, kx, vx = inp
+    def body(carry, inp):
+        x, kf, vf = carry
+        bp, layer, kx, vx = inp
         h = L.layernorm(x, bp["ln1"], cfg.norm_eps)
         positions = base_lens[:, None] + jnp.arange(T)[None, :]
         q, k, v = L.qkv_proj(bp["attn"], h, spec, positions)
-        kpl, vpl = scatter_kv_pages(
-            kpl, vpl, k, v, block_table, base_lens, t_lens
-        )
-        o = paged_verify_attention_op(
-            q, kpl, vpl, block_table, base_lens, softcap=spec.softcap
+        o, kf, vf = write_and_attend(
+            q, k, v, kf, vf, layer, n_pages, block_table, base_lens, t_lens,
+            softcap=spec.softcap,
         )
         x = x + L.attn_out(bp["attn"], o)
         h = L.layernorm(x, bp["lnx"], cfg.norm_eps)
         x = x + L.cross_attention(bp["xattn"], h, spec, kx, vx)
         h = L.layernorm(x, bp["ln2"], cfg.norm_eps)
         x = x + L.mlp_apply(bp["mlp"], h, cfg.gated_mlp, ctx=ctx)
-        return x, (kpl, vpl)
+        return (x, kf, vf), None
 
-    x, (k_pages, v_pages) = loops.scan(
-        body, x,
-        (params["dec_blocks"], k_pages, v_pages,
+    (x, kf, vf), _ = loops.scan(
+        body, (x, kf, vf),
+        (params["dec_blocks"], jnp.arange(n_layers, dtype=jnp.int32),
          mem_cache["k_mem"], mem_cache["v_mem"]),
     )
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return L.logits_out(x, params["embed"], tied=True), (k_pages, v_pages)
+    return L.logits_out(x, params["embed"], tied=True), (
+        kf.reshape(k_pages.shape), vf.reshape(v_pages.shape))
 
 
 def encdec_decode(cfg, params, tokens, cache, pos, *, ctx=NULL_CTX):

@@ -19,10 +19,7 @@ from repro.common import loops
 
 from repro.common.sharding import NULL_CTX
 from repro.configs.base import ArchConfig
-from repro.kernels.paged_attention.ops import (
-    paged_verify_attention_op,
-    scatter_kv_pages,
-)
+from repro.kernels.paged_attention.ops import flat_pools, write_and_attend
 from repro.models import layers as L
 from repro.models.moe import init_moe, moe_apply, moe_axes
 
@@ -418,25 +415,21 @@ def prefill(cfg: ArchConfig, params, batch, cache, *, ctx=NULL_CTX,
 
 
 def _apply_block_paged(
-    cfg, spec, bp, x, kp_l, vp_l, block_table, base_lens, t_lens, *, ctx,
-    dropless=True,
+    cfg, spec, bp, x, kf, vf, layer, n_pages, block_table, base_lens, t_lens,
+    *, ctx, dropless=True,
 ):
-    """Self-attn + FFN for new tokens against one layer's KV *pages*
-    (paged decode/verify; DESIGN.md §2).  New K/V are scattered through the
-    block table before attention so causality within the new block falls
-    out of the per-row length pointers."""
+    """Self-attn + FFN for new tokens against layer ``layer``'s KV pages in
+    the flat pools (paged decode/verify; DESIGN.md §2).  New K/V are
+    scattered through the block table before attention so causality within
+    the new block falls out of the per-row length pointers."""
     B, T, _ = x.shape
     h = L.rmsnorm(x, bp["ln1"], cfg.norm_eps)
     positions = base_lens[:, None] + jnp.arange(T)[None, :]
     q, k, v = L.qkv_proj(bp["attn"], h, spec, positions)
-    with jax.named_scope("scatter_kv"):
-        kp_l, vp_l = scatter_kv_pages(
-            kp_l, vp_l, k, v, block_table, base_lens, t_lens
-        )
-    with jax.named_scope("paged_attention"):
-        o = paged_verify_attention_op(
-            q, kp_l, vp_l, block_table, base_lens, softcap=spec.softcap
-        )
+    o, kf, vf = write_and_attend(
+        q, k, v, kf, vf, layer, n_pages, block_table, base_lens, t_lens,
+        softcap=spec.softcap,
+    )
     att = L.attn_out(bp["attn"], o)
     if cfg.sandwich_norm:
         att = L.rmsnorm(att, bp["ln1_post"], cfg.norm_eps)
@@ -451,7 +444,7 @@ def _apply_block_paged(
         f = L.mlp_apply(bp["mlp"], h, cfg.gated_mlp, ctx=ctx)
     if cfg.sandwich_norm:
         f = L.rmsnorm(f, bp["ln2_post"], cfg.norm_eps)
-    return x + f, kp_l, vp_l
+    return x + f, kf, vf
 
 
 def decode_paged(
@@ -471,11 +464,12 @@ def decode_paged(
     """Paged-cache analogue of ``decode``: serves ragged prefill (T = prompt
     suffix) and speculative verification (T = K+1) against `PagedKV` storage.
     Returns (logits, (k_pages, v_pages)) with the new tokens' K/V scattered
-    into the pages.  Requires full (non-windowed) attention — the paged
-    kernel has no sliding-window mask (engine falls back to dense
-    otherwise).  ``dropless`` controls MoE routing: True for verification
-    (composition independence), False for prompt prefill (GShard capacity,
-    matching the dense prefill path)."""
+    into the pages.  The layer scan carries the pools, as one flat pool
+    each, and writes them in place (``write_and_attend``).  Requires full
+    (non-windowed) attention — the paged kernel has no sliding-window mask
+    (engine falls back to dense otherwise).  ``dropless`` controls MoE
+    routing: True for verification (composition independence), False for
+    prompt prefill (GShard capacity, matching the dense prefill path)."""
     spec = attn_spec(cfg)
     if spec.window:
         raise ValueError("decode_paged does not support sliding-window attn")
@@ -484,50 +478,37 @@ def decode_paged(
     block_table = jnp.asarray(block_table, jnp.int32)
     x = _embed_in(cfg, params, tokens)
     x = ctx.cs(x, ("act_batch", None, "act_embed"))
+    n_layers, n_pages = k_pages.shape[:2]
+    kf, vf = flat_pools(k_pages, v_pages)
+    layers = jnp.arange(n_layers, dtype=jnp.int32)
+
+    def self_body(carry, inp):
+        bp, layer = inp
+        return _apply_block_paged(
+            cfg, spec, bp, *carry, layer, n_pages, block_table, base_lens,
+            t_lens, ctx=ctx, dropless=dropless,
+        ), None
 
     if cfg.cross_attn_every:
         per = cfg.cross_attn_every
-        n_groups = cfg.n_layers // per
-        kp = k_pages.reshape(n_groups, per, *k_pages.shape[1:])
-        vp = v_pages.reshape(n_groups, per, *v_pages.shape[1:])
 
-        def group_body(x, inp):
-            gp, kpg, vpg, kimg, vimg = inp
-
-            def self_body(xc, inner):
-                bp, kpl, vpl = inner
-                xo, kpl, vpl = _apply_block_paged(
-                    cfg, spec, bp, xc, kpl, vpl, block_table, base_lens,
-                    t_lens, ctx=ctx, dropless=dropless,
-                )
-                return xo, (kpl, vpl)
-
-            x, (kpg, vpg) = loops.scan(self_body, x, (gp["self"], kpg, vpg))
+        def group_body(carry, inp):
+            gp, group_layers, kimg, vimg = inp
+            (x, kf, vf), _ = loops.scan(
+                self_body, carry, (gp["self"], group_layers))
             x = _apply_cross_block(cfg, spec, gp["cross"], x, kimg, vimg, ctx=ctx)
-            return x, (kpg, vpg)
+            return (x, kf, vf), None
 
-        with jax.named_scope("layer_scan"):
-            x, (kp, vp) = loops.scan(
-                group_body, x,
-                (params["groups"], kp, vp,
-                 cross_cache["k_img"], cross_cache["v_img"]),
-            )
-        k_pages = kp.reshape(cfg.n_layers, *kp.shape[2:])
-        v_pages = vp.reshape(cfg.n_layers, *vp.shape[2:])
-        return _logits(cfg, params, x), (k_pages, v_pages)
-
-    def body(x, inp):
-        bp, kpl, vpl = inp
-        x, kpl, vpl = _apply_block_paged(
-            cfg, spec, bp, x, kpl, vpl, block_table, base_lens, t_lens,
-            ctx=ctx, dropless=dropless,
-        )
-        return x, (kpl, vpl)
+        body = group_body
+        xs = (params["groups"], layers.reshape(n_layers // per, per),
+              cross_cache["k_img"], cross_cache["v_img"])
+    else:
+        body, xs = self_body, (params["blocks"], layers)
 
     with jax.named_scope("layer_scan"):
-        x, (k_pages, v_pages) = loops.scan(
-            body, x, (params["blocks"], k_pages, v_pages))
-    return _logits(cfg, params, x), (k_pages, v_pages)
+        (x, kf, vf), _ = loops.scan(body, (x, kf, vf), xs)
+    return _logits(cfg, params, x), (kf.reshape(k_pages.shape),
+                                     vf.reshape(v_pages.shape))
 
 
 def vlm_cross_kv(cfg: ArchConfig, params, image_embeds):

@@ -143,12 +143,23 @@ def fused_paged_verify(decode_raw):
 
 def paged_prefill(decode_raw):
     """The paged backend's prefill program (module ``jit_prefill_paged``):
-    ``decode_raw`` over a batch of prompt chunks, returning ``(logits,
-    (k_pages, v_pages))``.  The page pools (args 2/3) are donated so XLA
+    ``decode_raw`` over a batch of prompt chunks, returning ``(first,
+    (k_pages, v_pages))``, where ``first[b]`` is the greedy token after
+    row b's last new token (position ``t_lens[b] - 1``): the first token
+    of a prompt that the chunk finishes.  The ``(B, T, V)`` logits stay
+    inside the program, so no second program reads them and no buffer of
+    that size outlives it.  The page pools (args 2/3) are donated so XLA
     updates pages in place instead of copying the whole pool (and
     transiently doubling KV memory) every call; the CPU ignores it."""
     def prefill_paged(params, feed, kp, vp, bt, base, tl, cross):
-        return decode_raw(params, feed, kp, vp, bt, base, tl, cross)
+        logits, pools = decode_raw(params, feed, kp, vp, bt, base, tl, cross)
+        # a masked sum over T picks each row's last position exactly, and
+        # the compiler fuses it into the unembedding, so the logits are
+        # never stored (for a v5e, a gather here kept them and copied
+        # slices of them besides)
+        last = jnp.arange(logits.shape[1])[None, :] == (tl - 1)[:, None]
+        row = jnp.where(last[..., None], logits, 0.0).sum(axis=1)
+        return jnp.argmax(row, axis=-1), pools
 
     return jax.jit(prefill_paged, donate_argnums=(2, 3))
 
@@ -947,7 +958,7 @@ class VerificationEngine:
         with span(tr, "wisp.prefill.launch", bucket=bucket):
             self._count_dispatch("prefill", bucket)
             feed_d, bt_d, base_d, tl_d, cross = args
-            logits, (kp, vp) = self._prefill_paged(
+            first_d, (kp, vp) = self._prefill_paged(
                 self.params, feed_d, self.kv.k_pages, self.kv.v_pages,
                 bt_d, base_d, tl_d, cross,
             )
@@ -959,21 +970,14 @@ class VerificationEngine:
             self.kv.set_len(st.slot, st.done)
             self.stats["prefill_chunks"] += 1
             if st.remaining == 0:
-                finished.append((i, n, st))
+                finished.append((i, st))
         if finished:
-            # one device-side argmax + ONE transfer for every chunk that
-            # completed its prompt this call (was: a blocking
-            # int(jnp.argmax(...)) sync per finished row)
+            # ONE transfer of the program's (B,) first tokens for every
+            # chunk that completed its prompt this call
             with span(tr, "wisp.prefill.wait", finished=len(finished)):
-                self._count_dispatch("first_token",
-                                     bucket[:2] + (len(finished),))
-                ridx = self._put([i for i, _, _ in finished], jnp.int32)
-                cpos = self._put([n - 1 for _, n, _ in finished], jnp.int32)
-                firsts = np.asarray(
-                    jax.device_get(jnp.argmax(logits[ridx, cpos], axis=-1))
-                )
-                for (_, _, st), first in zip(finished, firsts):
-                    self._finish_prefill(st, int(first))
+                firsts = np.asarray(jax.device_get(first_d))
+                for i, st in finished:
+                    self._finish_prefill(st, int(firsts[i]))
         return oom
 
     def _prefill_chunk_dense(self, st: PrefillState, n: int):

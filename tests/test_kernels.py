@@ -127,24 +127,32 @@ def test_paged_matches_dense_attention():
 
 def test_scatter_kv_pages_writes_head_major_pages():
     """Token t of row b lands at [page, head, offset] of the head-major
-    pool; positions past t_lens go to scratch page 0 and nowhere else."""
+    pool; positions past t_lens go to scratch page 0 and nowhere else.
+    In a flat pool of two layers, ``page_offset`` moves every write, the
+    scratch page's included, into layer 1's pages and leaves layer 0's."""
     rng = np.random.default_rng(5)
     n_pages, Hkv, page, D, T = 6, 2, 4, 8, 3
-    kp = jnp.zeros((n_pages, Hkv, page, D), jnp.float32)
     k_new = jnp.asarray(rng.normal(size=(2, T, Hkv, D)), jnp.float32)
     bt = jnp.asarray([[3, 5], [2, 4]], jnp.int32)
     base = jnp.asarray([3, 1], jnp.int32)            # row 0 crosses a page
     tl = jnp.asarray([3, 2], jnp.int32)              # row 1: last is padding
-    kp2, vp2 = scatter_kv_pages(kp, kp, k_new, -k_new, bt, base, tl)
-    want = np.zeros((n_pages, Hkv, page, D), np.float32)
-    for b, t, pid, off in [(0, 0, 3, 3), (0, 1, 5, 0), (0, 2, 5, 1),
-                           (1, 0, 2, 1), (1, 1, 2, 2)]:
-        want[pid, :, off] = np.asarray(k_new[b, t])
-    got = np.asarray(kp2)
-    np.testing.assert_array_equal(got[1:], want[1:])
-    np.testing.assert_array_equal(np.asarray(vp2)[1:], -want[1:])
-    # the padded token landed on the scratch page, offset 0
-    np.testing.assert_array_equal(got[0, :, 0], np.asarray(k_new[1, 2]))
+    for n_layers, layer in ((1, 0), (2, 0), (2, 1)):
+        kp = jnp.zeros((n_layers * n_pages, Hkv, page, D), jnp.float32)
+        kp2, vp2 = scatter_kv_pages(kp, kp, k_new, -k_new, bt, base, tl,
+                                    jnp.int32(layer * n_pages))
+        want = np.zeros((n_layers, n_pages, Hkv, page, D), np.float32)
+        for b, t, pid, off in [(0, 0, 3, 3), (0, 1, 5, 0), (0, 2, 5, 1),
+                               (1, 0, 2, 1), (1, 1, 2, 2)]:
+            want[layer, pid, :, off] = np.asarray(k_new[b, t])
+        got = np.asarray(kp2).reshape(want.shape)
+        vgot = np.asarray(vp2).reshape(want.shape)
+        live = [l for l in range(n_layers) if l != layer]
+        np.testing.assert_array_equal(got[live], want[live])
+        np.testing.assert_array_equal(got[layer, 1:], want[layer, 1:])
+        np.testing.assert_array_equal(vgot[layer, 1:], -want[layer, 1:])
+        # the padded token landed on the layer's scratch page, offset 0
+        np.testing.assert_array_equal(got[layer, 0, :, 0],
+                                      np.asarray(k_new[1, 2]))
 
 
 @pytest.mark.parametrize("platform,expected", [

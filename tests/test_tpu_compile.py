@@ -10,6 +10,9 @@ is described inside a fixture, by the one worker that runs this file.
 """
 import dataclasses
 import importlib.util
+import math
+import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +27,11 @@ from repro.kernels.paged_attention.ops import (
     scatter_kv_pages,
 )
 from repro.models import transformer
-from repro.serving.engine import fused_paged_verify, fused_paged_verify_specs
+from repro.serving.engine import (
+    fused_paged_verify,
+    fused_paged_verify_specs,
+    paged_prefill,
+)
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
 
@@ -119,3 +126,60 @@ def test_fused_paged_verify_compiles_for_v5e(one_chip, compiled_path):
         lambda *a: transformer.decode_paged(cfg, *a)
     )
     _check(program.lower(*specs, method="residual", tagged=True).compile())
+
+
+#: ops that copy a pool, or cut a layer's slice out of the stacked pools
+#: or write one back: matched by opcode, or by the name the compiler gives
+#: a fusion built around one (``copy_bitcast_fusion``,
+#: ``constant_dynamic-slice_fusion``, ``copy_dynamic-update-slice_fusion``)
+POOL_COPY_OPS = ("copy", "dynamic-slice", "dynamic-update-slice")
+_INSTR = re.compile(r"%(\S+) = \w+\[([\d,]*)\]\S* (\S+?)\(")
+
+
+def _pool_copies(hlo: str, shapes) -> list:
+    bad = []
+    for name, dims, op in _INSTR.findall(hlo):
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        if shape in shapes and any(k in op or k in name
+                                   for k in POOL_COPY_OPS):
+            bad.append(name)
+    return bad
+
+
+@pytest.mark.parametrize("program", ["verify", "prefill"])
+def test_paged_layer_scan_keeps_pools_in_place_for_v5e(
+        one_chip, compiled_path, program):
+    """The verify program and the prefill program (GShard MoE routing,
+    as the engine builds it) at qwen2-7b's widths write the bf16 pools in
+    place: no temp as large as one layer's slice of one pool (nor the
+    prefill's (B, T, V) logits, which stay fused into its first tokens),
+    and no copy, dynamic-slice or dynamic-update-slice that produces a
+    whole pool or a layer's slice.  Each flat pool (514 pages of 256 KiB)
+    is larger than the chip's 128 MiB of VMEM, as at the cell's size: a
+    smaller one may be staged whole into VMEM and back, which is a
+    placement a real pool never gets, not a per-layer copy."""
+    n_layers, n_pages, B, T = 2, 257, 4, 64
+    cfg = dataclasses.replace(get_config("qwen2-7b-14l"), n_layers=n_layers)
+    specs = fused_paged_verify_specs(
+        cfg, batch=B, k=6, n_pages=n_pages, n_max=8, top_c=64,
+        cache_dtype=jnp.bfloat16, sharding=one_chip,
+    )
+    if program == "verify":
+        compiled = fused_paged_verify(
+            partial(transformer.decode_paged, cfg)
+        ).lower(*specs, method="residual", tagged=True).compile()
+    else:
+        S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                   sharding=one_chip)
+        params, _, pool, _, bt, lens = specs[:6]
+        compiled = paged_prefill(
+            partial(transformer.decode_paged, cfg, dropless=False)
+        ).lower(params, S((B, T), jnp.int32), pool, pool, bt, lens, lens,
+                None).compile()
+    _check(compiled)
+    page = specs[2].shape[2:]                      # (Hkv, P, D)
+    layer_slice = n_pages * math.prod(page) * 2    # bytes of bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
+    shapes = {(n_layers, n_pages, *page), (n_layers * n_pages, *page),
+              (n_pages, *page), (1, n_pages, *page)}
+    assert _pool_copies(compiled.as_text(), shapes) == []

@@ -210,18 +210,19 @@ def test_cold_dispatches_count_first_launches():
     cfg, eng = _engine()
     assert "dispatches" not in eng.stats
     slot, _ = eng.new_session(G.PROMPTS[0])
-    # one prefill program and one first-token gather + argmax
-    assert eng.stats["cold_dispatches"] == 2
-    assert eng.dispatch_counts["first_token"] == 1
+    # one prefill program, which returns the first token itself
+    assert eng.stats["cold_dispatches"] == 1
+    assert eng.dispatch_counts["prefill"] == 1
+    assert "first_token" not in eng.dispatch_counts
     for rnd in range(3):
         toks, qlog = G._draft_for(cfg.vocab, 0, rnd)
         eng.verify([VerifyItem(slot=slot, draft_tokens=toks, q_logits=qlog)])
     assert eng.dispatch_counts["verify"] == 3
-    assert eng.stats["cold_dispatches"] == 3
+    assert eng.stats["cold_dispatches"] == 2
     toks, qlog = G._draft_for(cfg.vocab, 0, 9)
     eng.verify([VerifyItem(slot=slot, draft_tokens=toks[:1],
                            q_logits=qlog[:1])])      # K bucket 2, not 4
-    assert eng.stats["cold_dispatches"] == 4
+    assert eng.stats["cold_dispatches"] == 3
 
 
 def test_profiler_holds_each_step(tmp_path):
