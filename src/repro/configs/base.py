@@ -20,6 +20,9 @@ class MoEConfig:
     d_expert: int = 0            # per-expert FFN hidden (0 -> use d_ff)
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
+    # renormalize the top-k routing weights to sum to 1 (DeepSeekMoE's
+    # published ``norm_topk_prob: false`` keeps the softmax's own)
+    norm_topk_prob: bool = True
     # "tp": experts sharded on the FFN hidden dim (no all-to-all);
     # "ep": experts sharded on the expert dim (GSPMD inserts all-to-all).
     parallelism: str = "tp"

@@ -1,5 +1,6 @@
 """DeepSeekMoE-16B [arXiv:2401.06066]: fine-grained MoE, 2 shared + 64 routed
-experts top-6, per-expert hidden 1408 (d_ff field)."""
+experts top-6, per-expert hidden 1408 (d_ff field); softmax router whose
+top-6 weights are not renormalized (``norm_topk_prob: false``)."""
 from repro.configs.base import ArchConfig, MoEConfig, register
 
 CONFIG = register(
@@ -18,6 +19,7 @@ CONFIG = register(
             top_k=6,
             num_shared_experts=2,
             d_expert=1408,
+            norm_topk_prob=False,
             parallelism="ep",
         ),
     )

@@ -17,6 +17,13 @@ Parallelism:
   * "tp": expert FFN hidden dim sharded on `model` (dense-MLP-like comms);
   * "ep": expert dim sharded on `model` — GSPMD materializes the token
     all-to-all when resharding the dispatch buffer batch->expert.
+
+Serving (`moe_serve`: the paged verify and prefill programs, and the dense
+slot backend's decode) is dropless by construction instead: the (token, expert) assignments are sorted by expert
+and every routed expert runs as a grouped matmul over its contiguous rows
+(`kernels/moe_gmm`), so the work grows with top-k and not with the expert
+count, nothing is dropped, and a token's result does not depend on the
+other tokens in the batch (DESIGN.md §2).
 """
 from __future__ import annotations
 
@@ -24,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoEConfig
+from repro.kernels.moe_gmm.ops import moe_gmm_op, padded_rows
 from repro.models.layers import dense_param, _dense_init
 
 
@@ -70,18 +78,91 @@ def moe_axes(cfg: MoEConfig):
     return a
 
 
+def _top_k(probs, cfg: MoEConfig):
+    """The k largest routing probabilities and their experts, renormalized
+    to sum to 1 only where the model does (``norm_topk_prob``)."""
+    top_w, top_e = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    return top_w, top_e
+
+
+def _shared(sp, x):
+    """The always-on shared experts: one SwiGLU MLP of their summed width."""
+    sg = jnp.einsum("...d,df->...f", x, sp["gate"])
+    su = jnp.einsum("...d,df->...f", x, sp["up"])
+    return jnp.einsum("...f,fd->...d", jax.nn.silu(sg) * su, sp["down"])
+
+
+#: the routed experts' weights, which the paged layer scan hands the
+#: grouped matmul stacked over layers (`moe_serve`'s ``experts``)
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def moe_serve(p, x, cfg: MoEConfig, valid=None, *, experts=None, layer=None):
+    """Dropless routed experts for serving: x (B, T, D) -> (B, T, D).
+
+    Route: float32 router logits (``highest`` precision) and a softmax over
+    all E experts, then top-k.  Dispatch: the B*T*k assignments sorted by
+    expert (stable, so a token keeps its place within its expert), the
+    token rows gathered in that order, and each expert's group size
+    counted from the ids.  Experts: the grouped matmul of `moe_gmm_op`.
+    Combine: the rows put back in token order and summed under their
+    routing weights in float32, plus the shared experts.
+
+    ``valid`` (B, T) marks real tokens.  The rest (draft padding, pad
+    rows) route like any token, but their assignments sort after every
+    expert's group, so the grouped matmul never computes them, and their
+    routed part comes back 0.  Nothing of another token enters a token's
+    result, so it is the same in any batch.
+
+    ``experts`` (with ``layer``) gives the routed experts' weights stacked
+    over layers, ``(L, E, ...)``, and the layer to use; without it they
+    are ``p``'s own."""
+    B, T, D = x.shape
+    E, K = cfg.num_experts, cfg.top_k
+    N, M = B * T, B * T * cfg.top_k
+    xf = x.reshape(N, D)
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(xf.astype(jnp.float32), p["router"],
+                         precision=jax.lax.Precision.HIGHEST)   # (N, E)
+        top_w, top_e = _top_k(jax.nn.softmax(logits, axis=-1), cfg)
+    with jax.named_scope("moe_dispatch"):
+        expert = top_e.reshape(M)
+        if valid is not None:
+            expert = jnp.where(jnp.repeat(valid.reshape(N), K), expert, E)
+        order = jnp.argsort(expert, stable=True)                # (M,)
+        sizes = jnp.sum(expert[:, None] == jnp.arange(E), axis=0,
+                        dtype=jnp.int32)                        # (E,)
+        # pad rows to whole row tiles; they read token 0 and are not computed
+        rows = jnp.pad(order // K, (0, padded_rows(M) - M))
+        xs = jnp.take(xf, rows, axis=0)                         # (Mp, D)
+    with jax.named_scope("moe_experts"):
+        if experts is None:
+            experts, layer = tuple(p[k] for k in EXPERT_WEIGHTS), None
+        ys = moe_gmm_op(xs, *experts, sizes, layer)
+    with jax.named_scope("moe_combine"):
+        slot = jnp.zeros(M, jnp.int32).at[order].set(
+            jnp.arange(M, dtype=jnp.int32))                     # unsort
+        yk = jnp.take(ys, slot, axis=0).reshape(N, K, D)
+        y = (top_w[..., None] * yk).sum(axis=1)                 # f32
+        if valid is not None:
+            y = jnp.where(valid.reshape(N, 1), y, 0.0)
+        y = y.astype(x.dtype)
+        if cfg.num_shared_experts:
+            y = y + _shared(p["shared"], xf)
+    return y.reshape(B, T, D)
+
+
 def expert_capacity(cfg: MoEConfig, tokens_per_group: int) -> int:
     C = int(round(tokens_per_group * cfg.top_k / cfg.num_experts * cfg.capacity_factor))
     return max(cfg.top_k, min(C, tokens_per_group))
 
 
-def moe_apply(p, x, cfg: MoEConfig, *, ctx=None, rng=None, dropless=False):
-    """x: (B, S, D) -> ((B, S, D), aux losses).  Groups = batch rows.
-
-    ``dropless=True`` (decode/verify paths) sets capacity = S so no
-    assignment is ever dropped: speculative verification must be a
-    deterministic function of the context, independent of how many draft
-    tokens share the microbatch.  Training keeps GShard capacity semantics.
+def moe_apply(p, x, cfg: MoEConfig, *, ctx=None, rng=None):
+    """x: (B, S, D) -> ((B, S, D), aux losses).  Groups = batch rows, with
+    GShard capacity (training and the dense slot backend's prefill).
+    Verification and the paged serving programs use `moe_serve` instead.
     """
     B, S, D = x.shape
     E, K = cfg.num_experts, cfg.top_k
@@ -92,10 +173,9 @@ def moe_apply(p, x, cfg: MoEConfig, *, ctx=None, rng=None, dropless=False):
     if cfg.router_jitter and rng is not None:
         logits = logits + cfg.router_jitter * jax.random.normal(rng, logits.shape)
     probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, K)                      # (B, S, K)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    top_w, top_e = _top_k(probs, cfg)                           # (B, S, K)
 
-    C = S if dropless else expert_capacity(cfg, S)
+    C = expert_capacity(cfg, S)
 
     # rank within (group, expert): cumulative count along the S*K axis
     flat_e = top_e.reshape(B, S * K)                            # (B, S*K)
@@ -136,11 +216,7 @@ def moe_apply(p, x, cfg: MoEConfig, *, ctx=None, rng=None, dropless=False):
     y = (gathered * w[..., None]).reshape(B, S, K, D).sum(axis=2)
 
     if cfg.num_shared_experts:
-        sg = jnp.einsum("bsd,df->bsf", x, p["shared"]["gate"])
-        su = jnp.einsum("bsd,df->bsf", x, p["shared"]["up"])
-        y = y + jnp.einsum(
-            "bsf,fd->bsd", jax.nn.silu(sg) * su, p["shared"]["down"]
-        )
+        y = y + _shared(p["shared"], x)
 
     # load-balance aux loss (Switch-style)
     me = probs.reshape(-1, E).mean(axis=0)

@@ -21,7 +21,13 @@ from repro.common.sharding import NULL_CTX
 from repro.configs.base import ArchConfig
 from repro.kernels.paged_attention.ops import flat_pools, write_and_attend
 from repro.models import layers as L
-from repro.models.moe import init_moe, moe_apply, moe_axes
+from repro.models.moe import (
+    EXPERT_WEIGHTS,
+    init_moe,
+    moe_apply,
+    moe_axes,
+    moe_serve,
+)
 
 
 def attn_spec(cfg: ArchConfig) -> L.AttnSpec:
@@ -161,7 +167,7 @@ def param_axes(cfg: ArchConfig):
 # ---------------------------------------------------------------------------
 
 
-def _apply_block_full(cfg, spec, bp, x, *, local, ctx, kv_chunk=1024, dropless=False):
+def _apply_block_full(cfg, spec, bp, x, *, local, ctx, kv_chunk=1024):
     """Self-attn + FFN over the full sequence (train/prefill). Returns
     (x, (k, v), aux)."""
     h = L.rmsnorm(x, bp["ln1"], cfg.norm_eps)
@@ -175,7 +181,7 @@ def _apply_block_full(cfg, spec, bp, x, *, local, ctx, kv_chunk=1024, dropless=F
     h = L.rmsnorm(x, bp["ln2"], cfg.norm_eps)
     aux = {}
     if cfg.moe is not None:
-        f, aux = moe_apply(bp["moe"], h, cfg.moe, ctx=ctx, dropless=dropless)
+        f, aux = moe_apply(bp["moe"], h, cfg.moe, ctx=ctx)
     else:
         f = L.mlp_apply(bp["mlp"], h, cfg.gated_mlp, ctx=ctx)
     if cfg.sandwich_norm:
@@ -197,7 +203,7 @@ def _apply_block_cached(cfg, spec, bp, x, kc, vc, pos, *, local, ctx):
     h = L.rmsnorm(x, bp["ln2"], cfg.norm_eps)
     if cfg.moe is not None:
         # dropless: verification must not depend on microbatch composition
-        f, _ = moe_apply(bp["moe"], h, cfg.moe, ctx=ctx, dropless=True)
+        f = moe_serve(bp["moe"], h, cfg.moe)
     else:
         f = L.mlp_apply(bp["mlp"], h, cfg.gated_mlp, ctx=ctx)
     if cfg.sandwich_norm:
@@ -276,7 +282,7 @@ def chunked_ce_loss(cfg, params, x, targets, *, ctx=NULL_CTX, chunk=512):
 
 
 def _run_layers_full(cfg, params, x, *, ctx, collect_kv, image_embeds=None,
-                     dropless=False, remat=False):
+                     remat=False):
     """Returns (x, kv_stack or None, cross_kv or None, aux)."""
     spec = attn_spec(cfg)
     ckpt = (
@@ -296,7 +302,7 @@ def _run_layers_full(cfg, params, x, *, ctx, collect_kv, image_embeds=None,
             def self_body(xc, inp):
                 bp, loc = inp
                 xo, (k, v), aux = _apply_block_full(
-                    cfg, spec, bp, xc, local=loc, ctx=ctx, dropless=dropless
+                    cfg, spec, bp, xc, local=loc, ctx=ctx
                 )
                 return xo, (k, v)
 
@@ -317,7 +323,7 @@ def _run_layers_full(cfg, params, x, *, ctx, collect_kv, image_embeds=None,
         x, lb = carry
         bp, loc = inp
         x, (k, v), aux = _apply_block_full(
-            cfg, spec, bp, x, local=loc, ctx=ctx, dropless=dropless
+            cfg, spec, bp, x, local=loc, ctx=ctx
         )
         lb = lb + aux.get("load_balance", 0.0)
         return (x, lb), ((k, v) if collect_kv else None)
@@ -388,16 +394,14 @@ def prefill(cfg: ArchConfig, params, batch, cache, *, ctx=NULL_CTX,
     B, S = tokens.shape
     x = _embed_in(cfg, params, tokens)
     x = ctx.cs(x, ("act_batch", "act_seq", "act_embed"))
-    # MoE prefill uses GShard capacity semantics, NOT dropless: dropless
-    # capacity C=S inflates dispatch buffers by E/topk (8x for grok —
-    # §Perf cell B) and is unnecessary for WISP's composition-independence:
-    # routing groups are batch rows, so capacity ranking depends only on
-    # the request's own tokens either way.  The verify path (decode, T
-    # small) stays exact-dropless where determinism is load-bearing.
+    # The dense slot backend's MoE prefill keeps GShard capacity, as
+    # training does: routing groups are batch rows, so the ranking depends
+    # only on the request's own tokens.  Its decode and the paged programs
+    # route every token through the dropless grouped experts
+    # (`moe.moe_serve`).
     x, kv_stack, cross_kv, _ = _run_layers_full(
         cfg, params, x, ctx=ctx, collect_kv=True,
         image_embeds=batch.get("image_embeds"),
-        dropless=False,
     )
     k_new, v_new = kv_stack
     upd = lambda c, n: jax.lax.dynamic_update_slice_in_dim(
@@ -416,12 +420,13 @@ def prefill(cfg: ArchConfig, params, batch, cache, *, ctx=NULL_CTX,
 
 def _apply_block_paged(
     cfg, spec, bp, x, kf, vf, layer, n_pages, block_table, base_lens, t_lens,
-    *, ctx, dropless=True,
+    *, ctx, experts=None,
 ):
     """Self-attn + FFN for new tokens against layer ``layer``'s KV pages in
     the flat pools (paged decode/verify; DESIGN.md §2).  New K/V are
     scattered through the block table before attention so causality within
-    the new block falls out of the per-row length pointers."""
+    the new block falls out of the per-row length pointers.  ``experts``:
+    the MoE experts' weights stacked over layers (`moe.moe_serve`)."""
     B, T, _ = x.shape
     h = L.rmsnorm(x, bp["ln1"], cfg.norm_eps)
     positions = base_lens[:, None] + jnp.arange(T)[None, :]
@@ -436,10 +441,11 @@ def _apply_block_paged(
     x = x + att
     h = L.rmsnorm(x, bp["ln2"], cfg.norm_eps)
     if cfg.moe is not None:
-        # verify: dropless, so results cannot depend on microbatch
-        # composition; prefill passes dropless=False (GShard capacity,
-        # matching the dense prefill path — see `prefill`'s rationale)
-        f, _ = moe_apply(bp["moe"], h, cfg.moe, ctx=ctx, dropless=dropless)
+        # verify and prefill alike: dropless grouped experts, pad
+        # positions left out (a token's result cannot depend on the batch)
+        valid = jnp.arange(T)[None, :] < t_lens[:, None]
+        f = moe_serve(bp["moe"], h, cfg.moe, valid, experts=experts,
+                      layer=layer)
     else:
         f = L.mlp_apply(bp["mlp"], h, cfg.gated_mlp, ctx=ctx)
     if cfg.sandwich_norm:
@@ -467,9 +473,11 @@ def decode_paged(
     into the pages.  The layer scan carries the pools, as one flat pool
     each, and writes them in place (``write_and_attend``).  Requires full
     (non-windowed) attention — the paged kernel has no sliding-window mask
-    (engine falls back to dense otherwise).  ``dropless`` controls MoE
-    routing: True for verification (composition independence), False for
-    prompt prefill (GShard capacity, matching the dense prefill path)."""
+    (engine falls back to dense otherwise).  MoE layers route through the
+    dropless grouped experts (`moe.moe_serve`) for verification and prompt
+    prefill alike, so the result is exact under either ``dropless``; the
+    keyword stays for callers that still pass it."""
+    del dropless
     spec = attn_spec(cfg)
     if spec.window:
         raise ValueError("decode_paged does not support sliding-window attn")
@@ -481,12 +489,20 @@ def decode_paged(
     n_layers, n_pages = k_pages.shape[:2]
     kf, vf = flat_pools(k_pages, v_pages)
     layers = jnp.arange(n_layers, dtype=jnp.int32)
+    blocks, experts = params.get("blocks"), None
+    if cfg.moe is not None and not cfg.cross_attn_every:
+        # the experts stay stacked outside the scan and the grouped matmul
+        # reads layer ``l``'s from the stack: scanned, each layer's would be
+        # cut out and copied for the kernel
+        moe = dict(blocks["moe"])
+        experts = tuple(moe.pop(k) for k in EXPERT_WEIGHTS)
+        blocks = {**blocks, "moe": moe}
 
     def self_body(carry, inp):
         bp, layer = inp
         return _apply_block_paged(
             cfg, spec, bp, *carry, layer, n_pages, block_table, base_lens,
-            t_lens, ctx=ctx, dropless=dropless,
+            t_lens, ctx=ctx, experts=experts,
         ), None
 
     if cfg.cross_attn_every:
@@ -503,7 +519,7 @@ def decode_paged(
         xs = (params["groups"], layers.reshape(n_layers // per, per),
               cross_cache["k_img"], cross_cache["v_img"])
     else:
-        body, xs = self_body, (params["blocks"], layers)
+        body, xs = self_body, (blocks, layers)
 
     with jax.named_scope("layer_scan"):
         (x, kf, vf), _ = loops.scan(body, (x, kf, vf), xs)

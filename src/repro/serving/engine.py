@@ -368,6 +368,11 @@ class VerificationEngine:
             #: the existing bucket/pad machinery — per-row ``dlen`` masks
             #: the pad tail, so mixed-K costs no extra dispatch
             "mixed_k_batches": 0,
+            #: MoE targets, counted on the host from each program's shapes:
+            #: routed (token, expert) assignments of real tokens (tokens x
+            #: top-k x layers), and expert-layer passes (calls x layers)
+            "moe_assignments": 0,
+            "moe_expert_passes": 0,
             #: host spill tier (DESIGN.md §12): bytes moved across the
             #: device<->host boundary by spill / page-in, plus format
             #: counters — structurally zero when no tier is configured
@@ -436,9 +441,6 @@ class VerificationEngine:
             self._extras_key = "image_embeds"
             self._extras_builder = jax.jit(partial(transformer.vlm_cross_kv, cfg))
             decode_raw = partial(transformer.decode_paged, cfg)
-            self._prefill_paged = paged_prefill(
-                partial(transformer.decode_paged, cfg, dropless=False)
-            )
         elif cfg.family == "audio":
             z = lambda: jnp.zeros(
                 (cfg.n_layers, self.max_slots, cfg.encoder_frames, hkv, hd),
@@ -448,14 +450,9 @@ class VerificationEngine:
             self._extras_key = "frames"
             self._extras_builder = jax.jit(partial(encdec.encdec_cross_kv, cfg))
             decode_raw = partial(encdec.encdec_decode_paged, cfg)
-            self._prefill_paged = paged_prefill(decode_raw)  # no MoE routing
         else:
             decode_raw = partial(transformer.decode_paged, cfg)
-            # prompt prefill keeps GShard capacity MoE routing, matching
-            # the dense `prefill` path (verify stays dropless)
-            self._prefill_paged = paged_prefill(
-                partial(transformer.decode_paged, cfg, dropless=False)
-            )
+        self._prefill_paged = paged_prefill(decode_raw)
         self._fused_verify = fused_paged_verify(decode_raw)
 
     # -- fused per-epoch verify programs (DESIGN.md §9) -----------------------
@@ -667,6 +664,13 @@ class VerificationEngine:
         if (name, bucket) not in self._launched:
             self._launched.add((name, bucket))
             self.stats["cold_dispatches"] += 1
+
+    def _count_moe(self, tokens: int):
+        """One target call over ``tokens`` real tokens, in the MoE counters."""
+        if self.cfg.moe is not None:
+            L = self.cfg.n_layers
+            self.stats["moe_assignments"] += tokens * self.cfg.moe.top_k * L
+            self.stats["moe_expert_passes"] += L
 
     def _pool(self, key: tuple, spec: dict) -> dict:
         """Fetch (or build) the pooled buffer set for ``key``.  On reuse,
@@ -963,6 +967,7 @@ class VerificationEngine:
                 bt_d, base_d, tl_d, cross,
             )
             self.kv.k_pages, self.kv.v_pages = kp, vp
+        self._count_moe(sum(n for _, n in live))
         finished: list = []
         for i, (st, n) in enumerate(live):
             st.done += n
@@ -1011,6 +1016,7 @@ class VerificationEngine:
                 self.params, self._put(padded), sub, jnp.int32(s0)
             )
         self._scatter([st.slot], sub, 1)
+        self._count_moe(n)
         st.done += n
         st.chunks += 1
         self.stats["prefill_chunks"] += 1
@@ -1275,4 +1281,5 @@ class VerificationEngine:
         self.stats["batches"] += 1
         self.stats["tokens_verified"] += int(bufs["dlen"][:n].sum())
         self.stats["tokens_committed"] += int(accs.sum()) + n
+        self._count_moe(int(bufs["tl"][:n].sum()))
         return results

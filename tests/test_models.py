@@ -77,9 +77,11 @@ def test_decode_matches_forward_teacher_forced(arch, rng):
 
     cfg = get_config(arch).reduced()
     if cfg.moe is not None:
-        # prefill uses capacity routing (EXPERIMENTS §Perf cell B) while
-        # verify is exact-dropless; they agree whenever nothing drops, so
-        # test at a capacity factor that guarantees no drops
+        # these are the dense slot backend's entry points: its prefill
+        # keeps GShard capacity routing, as training does, and its decode
+        # runs the dropless grouped experts (`moe_serve`), as the paged
+        # programs do (tests/test_moe_serving.py); they agree whenever
+        # nothing drops, so test at a capacity factor that guarantees none
         cfg = dataclasses.replace(
             cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=100.0)
         )

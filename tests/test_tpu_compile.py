@@ -183,3 +183,46 @@ def test_paged_layer_scan_keeps_pools_in_place_for_v5e(
     shapes = {(n_layers, n_pages, *page), (n_layers * n_pages, *page),
               (n_pages, *page), (1, n_pages, *page)}
     assert _pool_copies(compiled.as_text(), shapes) == []
+
+
+@pytest.mark.parametrize("program", ["verify", "prefill"])
+def test_moe_programs_run_grouped_experts_for_v5e(one_chip, compiled_path,
+                                                   program):
+    """deepseek-moe-16b at 7 layers and its published widths (64 experts
+    of 1408, top-6, 2 shared), with a pool of 33 pages (231 MiB a pool,
+    more than the chip's VMEM): both paged programs compile with the
+    grouped expert kernel (`moe_gmm`) and build no expert buffer, neither
+    GShard's at capacity nor a dropless one at the row length:
+    ``(B, E * C, D)``, ``(B, E, C, D)`` or ``(B, E, C, F)``."""
+    from repro.models.moe import expert_capacity
+
+    cfg = dataclasses.replace(get_config("deepseek-moe-16b"), n_layers=7)
+    B, n_pages = 4, 33
+    T = 7 if program == "verify" else 48
+    specs = fused_paged_verify_specs(
+        cfg, batch=B, k=T - 1, n_pages=n_pages, n_max=8, top_c=8,
+        cache_dtype=jnp.bfloat16, sharding=one_chip,
+    )
+    decode = partial(transformer.decode_paged, cfg)
+    if program == "verify":
+        compiled = fused_paged_verify(decode).lower(
+            *specs, method="residual", tagged=True).compile()
+    else:
+        S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                   sharding=one_chip)
+        params, _, pool, _, bt, lens = specs[:6]
+        compiled = paged_prefill(decode).lower(
+            params, S((B, T), jnp.int32), pool, pool, bt, lens, lens,
+            None).compile()
+    _check(compiled)
+    hlo = compiled.as_text()
+    assert re.search(r"%moe_gmm_gated\S* = .*custom-call", hlo)
+    assert re.search(r"%moe_gmm_down\S* = .*custom-call", hlo)
+    m, D = cfg.moe, cfg.d_model
+    E, F = m.num_experts, m.d_expert
+    buffers = set()
+    for C in (T, expert_capacity(m, T)):
+        buffers |= {(B, E * C, D), (B, E, C, D), (B, E, C, F)}
+    shapes = {tuple(int(d) for d in dims.split(",") if d)
+              for _, dims, _ in _INSTR.findall(hlo)}
+    assert not shapes & buffers
